@@ -1,38 +1,33 @@
-"""Single version-resolution choke point for drifted JAX APIs.
+"""The few JAX API uses that the substrate routes through one module.
 
-The repo targets JAX 0.4.35 through current releases. A handful of APIs the
-substrate depends on were renamed or reshaped across 0.4.x -> 0.6.x:
+The repo targets the one installed JAX (0.9.0, pinned in ``pyproject.toml``).
+These helpers keep the call sites short and give a future JAX upgrade one
+file to edit:
 
-===============================  ==========================  =====================
-API                              0.4.x                       0.5+/0.6+
-===============================  ==========================  =====================
-Pallas TPU compiler params       ``pltpu.TPUCompilerParams`` ``pltpu.CompilerParams``
-Mesh axis types                  (absent)                    ``jax.sharding.AxisType``
-``jax.make_mesh`` axis_types kw  (absent)                    present
-Ambient mesh setter              ``with mesh:`` (resource    ``jax.set_mesh`` /
-                                 env context manager)        ``jax.sharding.use_mesh``
-Ambient mesh getter              (absent)                    ``jax.sharding.get_abstract_mesh``
-``compiled.cost_analysis()``     one-element ``list``        ``dict``
-``memory_analysis()`` peak       (absent)                    ``peak_memory_in_bytes``
-===============================  ==========================  =====================
+===============================  ==============================================
+Helper                           JAX API
+===============================  ==============================================
+``tpu_compiler_params``          ``jax.experimental.pallas.tpu.CompilerParams``
+``get_mesh_axis_types``          ``jax.sharding.AxisType``
+``make_mesh``                    ``jax.make_mesh(..., axis_types=...)``
+``set_mesh``                     ``jax.set_mesh``
+``current_abstract_mesh``        ``jax.sharding.get_abstract_mesh``
+``normalize_cost_analysis``      ``compiled.cost_analysis()`` (a dict)
+``normalize_memory_analysis``    ``compiled.memory_analysis()``
+===============================  ==============================================
 
-**Repo rule (see README):** no module outside this one may touch a
-version-divergent JAX API directly. Everything routes through the shims
-below, so a new JAX release is absorbed by editing exactly one file. The
-acceptance grep for this rule is::
+**Repo rule (see README):** no module outside this one touches these APIs
+directly. The acceptance grep for this rule is::
 
     grep -rn "CompilerParams\\|AxisType\\|get_abstract_mesh" src/repro \\
         --include="*.py" | grep -v compat.py   # must return no hits
 
-All resolution is lazy and cached: importing this module never initializes
-JAX device state (the dry-run sets ``XLA_FLAGS`` before the first device
-query and must keep that window open).
+Importing this module never initializes JAX device state (the dry-run sets
+``XLA_FLAGS`` before the first device query and must keep that window open).
 """
 
 from __future__ import annotations
 
-import functools
-import inspect
 import re
 from contextlib import contextmanager
 from typing import Any, Dict, Iterator, Optional, Sequence, Tuple
@@ -63,51 +58,25 @@ def jax_version() -> Tuple[int, ...]:
     return tuple(parts)
 
 
-# --------------------------------------------------------------------------
-# Pallas TPU compiler params: TPUCompilerParams (0.4.x) -> CompilerParams.
-# --------------------------------------------------------------------------
-
-
-@functools.lru_cache(maxsize=None)
-def _compiler_params_cls():
-    from jax.experimental.pallas import tpu as pltpu
-
-    for name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            return cls
-    raise AttributeError(
-        "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-        f"TPUCompilerParams (jax {jax.__version__})"
-    )
-
-
 def tpu_compiler_params(
     *, dimension_semantics: Optional[Sequence[str]] = None, **kwargs: Any
 ):
-    """Mosaic compiler-params object under whichever name this JAX uses.
+    """Mosaic compiler-params object (``pltpu.CompilerParams``).
 
     Accepts the same keywords as the underlying class; ``dimension_semantics``
     is the one every kernel in the repo passes.
     """
+    from jax.experimental.pallas import tpu as pltpu
+
     if dimension_semantics is not None:
         kwargs["dimension_semantics"] = tuple(dimension_semantics)
-    return _compiler_params_cls()(**kwargs)
+    return pltpu.CompilerParams(**kwargs)
 
 
-# --------------------------------------------------------------------------
-# Mesh construction: AxisType and the make_mesh axis_types kwarg are 0.5+.
-# --------------------------------------------------------------------------
-
-
-def get_mesh_axis_types(n_axes: int, kind: str = "auto") -> Optional[tuple]:
-    """``(AxisType.<kind>,) * n_axes`` — or None when this JAX predates
-    ``jax.sharding.AxisType`` (0.4.x, where all axes are implicitly auto)."""
-    axis_type_cls = getattr(jax.sharding, "AxisType", None)
-    if axis_type_cls is None:
-        return None
+def get_mesh_axis_types(n_axes: int, kind: str = "auto") -> tuple:
+    """``(AxisType.<kind>,) * n_axes``."""
     member = {"auto": "Auto", "explicit": "Explicit", "manual": "Manual"}[kind]
-    return (getattr(axis_type_cls, member),) * n_axes
+    return (getattr(jax.sharding.AxisType, member),) * n_axes
 
 
 def make_mesh(
@@ -117,110 +86,39 @@ def make_mesh(
     axis_types: Any = "auto",
     devices=None,
 ):
-    """``jax.make_mesh`` that tolerates the missing ``axis_types`` kwarg.
-
-    ``axis_types`` may be an AxisType kind name ("auto"/"explicit"/"manual")
-    or an explicit tuple; on 0.4.x it is dropped (the only behaviour that
-    version supports is auto).
-    """
-    kwargs: Dict[str, Any] = {}
-    if devices is not None:
-        kwargs["devices"] = devices
+    """``jax.make_mesh`` with ``axis_types`` given as a kind name
+    ("auto"/"explicit"/"manual") or an explicit tuple. The default is auto:
+    ``jax.make_mesh`` itself defaults to explicit axes."""
     if isinstance(axis_types, str):
         axis_types = get_mesh_axis_types(len(axis_names), axis_types)
-    if axis_types is not None and _make_mesh_supports_axis_types():
-        kwargs["axis_types"] = axis_types
+    kwargs: Dict[str, Any] = {"axis_types": axis_types}
+    if devices is not None:
+        kwargs["devices"] = devices
     return jax.make_mesh(axis_shapes, axis_names, **kwargs)
-
-
-@functools.lru_cache(maxsize=None)
-def _make_mesh_supports_axis_types() -> bool:
-    # Feature-detect the kwarg instead of catching TypeError around the call:
-    # a malformed axis_types value also raises TypeError, and that error must
-    # surface, not silently downgrade the mesh to default axis types.
-    return "axis_types" in inspect.signature(jax.make_mesh).parameters
-
-
-# --------------------------------------------------------------------------
-# Ambient mesh: jax.set_mesh / get_abstract_mesh are 0.5+; on 0.4.x the
-# equivalent is the resource-env context manager (``with mesh:``) plus a
-# module-level stack so the getter below can answer.
-# --------------------------------------------------------------------------
-
-_MESH_STACK: list = []
 
 
 @contextmanager
 def set_mesh(mesh) -> Iterator[Any]:
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    Resolves to ``jax.set_mesh`` when present, then ``jax.sharding.use_mesh``
-    (the 0.5.x-era context manager), then the physical mesh's own
-    resource-env context (the classic pjit idiom). The mesh is also recorded
-    on a module-level stack so :func:`current_abstract_mesh` can answer even
-    when the installed getter does not see this setter's effect.
-    """
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        with setter(mesh):
-            yield mesh
-        return
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    _MESH_STACK.append(mesh)
-    try:
-        with (use_mesh(mesh) if use_mesh is not None else mesh):
-            yield mesh
-    finally:
-        _MESH_STACK.pop()
+    """Context manager installing ``mesh`` as the ambient mesh."""
+    with jax.set_mesh(mesh):
+        yield mesh
 
 
 def current_abstract_mesh():
-    """The ambient mesh, or None when none is set.
-
-    On 0.5+ this is ``jax.sharding.get_abstract_mesh()`` (an AbstractMesh,
-    possibly empty). On 0.4.x it falls back to the physical mesh installed by
-    :func:`set_mesh` (or by a raw ``with mesh:`` resource env). Callers must
-    treat "None / empty axis_names" as "no mesh".
-    """
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        ambient = getter()
-        if getattr(ambient, "axis_names", ()) or ():
-            return ambient
-        # Empty abstract mesh but a mesh on our stack: the installed setter
-        # (``with mesh:`` fallback) doesn't feed this getter — answer from
-        # the stack instead of reporting "no mesh".
-        return _MESH_STACK[-1] if _MESH_STACK else ambient
-    if _MESH_STACK:
-        return _MESH_STACK[-1]
-    try:  # raw `with mesh:` without our set_mesh — best-effort recovery
-        from jax._src import mesh as _mesh_lib
-
-        physical = _mesh_lib.thread_resources.env.physical_mesh
-        if physical is not None and not physical.empty:
-            return physical
-    except Exception:
-        pass
-    return None
+    """The ambient abstract mesh, or None when none is set."""
+    ambient = jax.sharding.get_abstract_mesh()
+    return ambient if ambient.axis_names else None
 
 
 def mesh_axis_sizes(mesh) -> Dict[str, int]:
-    """``{axis_name: size}`` for Mesh and AbstractMesh across versions."""
+    """``{axis_name: size}`` for a Mesh or AbstractMesh (``{}`` for None)."""
     if mesh is None:
         return {}
-    sizes = getattr(mesh, "axis_sizes", None)
-    if sizes is not None:
-        return dict(zip(mesh.axis_names, (int(s) for s in sizes)))
-    return {str(k): int(v) for k, v in dict(getattr(mesh, "shape", {})).items()}
-
-
-# --------------------------------------------------------------------------
-# cost_analysis: dict on recent JAX, one-element list of dicts on 0.4.x.
-# --------------------------------------------------------------------------
+    return dict(zip(mesh.axis_names, (int(s) for s in mesh.axis_sizes)))
 
 
 def normalize_cost_analysis(compiled_or_result) -> Dict[str, float]:
-    """Uniform dict view of ``compiled.cost_analysis()``.
+    """Dict view of ``compiled.cost_analysis()``.
 
     Accepts either the compiled executable or the raw ``cost_analysis()``
     return value; None (backends that report nothing) becomes ``{}``.
@@ -228,40 +126,19 @@ def normalize_cost_analysis(compiled_or_result) -> Dict[str, float]:
     result = compiled_or_result
     if hasattr(result, "cost_analysis"):
         result = result.cost_analysis()
-    if result is None:
-        return {}
-    if isinstance(result, (list, tuple)):
-        result = result[0] if result else {}
-    return dict(result)
+    return dict(result or {})
 
 
 def normalize_memory_analysis(compiled_or_stats) -> Dict[str, int]:
-    """Uniform dict view of ``compiled.memory_analysis()``.
-
-    ``peak_bytes`` is the buffer-assignment high-water mark where the
-    runtime reports one (``peak_memory_in_bytes``, newer JAX); on 0.4.x it is
-    bounded above by arguments + outputs + temps - aliased bytes.
-    """
+    """Dict view of ``compiled.memory_analysis()``; ``peak_bytes`` is the
+    buffer-assignment high-water mark (``peak_memory_in_bytes``)."""
     stats = compiled_or_stats
     if hasattr(stats, "memory_analysis"):
         stats = stats.memory_analysis()
-
-    def grab(name: str) -> int:
-        return int(getattr(stats, name, 0) or 0)
-
-    out = {
-        "argument_bytes": grab("argument_size_in_bytes"),
-        "output_bytes": grab("output_size_in_bytes"),
-        "temp_bytes": grab("temp_size_in_bytes"),
-        "alias_bytes": grab("alias_size_in_bytes"),
+    return {
+        "argument_bytes": int(stats.argument_size_in_bytes),
+        "output_bytes": int(stats.output_size_in_bytes),
+        "temp_bytes": int(stats.temp_size_in_bytes),
+        "alias_bytes": int(stats.alias_size_in_bytes),
+        "peak_bytes": max(int(stats.peak_memory_in_bytes), 0),
     }
-    peak = getattr(stats, "peak_memory_in_bytes", None)
-    if peak is None:
-        peak = (
-            out["argument_bytes"]
-            + out["output_bytes"]
-            + out["temp_bytes"]
-            - out["alias_bytes"]
-        )
-    out["peak_bytes"] = max(int(peak), 0)
-    return out

@@ -11,8 +11,9 @@ derive from the compiled module (all inputs per device, post-SPMD):
 ``cost_analysis`` is already per device, so the division by chip count has
 already happened.)
 
-Hardware constants (TPU v5e, per brief): 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI.
+Hardware constants live in :data:`PEAKS_BY_DEVICE_KIND`, keyed by JAX's
+``device_kind``; :func:`device_peaks` picks the entry for the device a run
+is on.
 """
 
 from __future__ import annotations
@@ -23,6 +24,8 @@ from typing import Dict, Optional
 __all__ = [
     "HardwareSpec",
     "TPU_V5E",
+    "PEAKS_BY_DEVICE_KIND",
+    "device_peaks",
     "RooflineTerms",
     "roofline_terms",
     "model_flops",
@@ -41,9 +44,41 @@ class HardwareSpec:
     ici_link_bw: float  # bytes/s per link
 
 
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 and 819 GB/s of
+# HBM bandwidth per chip. The ICI figure is a per-link planning estimate.
 TPU_V5E = HardwareSpec(
     name="tpu-v5e", peak_flops=197e12, hbm_bw=819e9, ici_link_bw=50e9
 )
+
+# Per-chip peaks keyed by ``jax.Device.device_kind``. A v5e reports itself
+# as "TPU v5 lite" (older runtimes: "TPU v5e").
+PEAKS_BY_DEVICE_KIND: Dict[str, HardwareSpec] = {
+    "TPU v5 lite": TPU_V5E,
+    "TPU v5e": TPU_V5E,
+}
+
+
+def device_peaks(device=None) -> HardwareSpec:
+    """Peaks of ``device`` (default: the first JAX device).
+
+    A TPU whose kind is not in :data:`PEAKS_BY_DEVICE_KIND` raises: scoring
+    it against another chip's peaks would report a wrong utilization. A
+    non-TPU device (the CPU test runs) is scored against the v5e as a
+    labelled reference; nothing from it is a device number.
+    """
+    if device is None:
+        import jax
+
+        device = jax.devices()[0]
+    if device.platform != "tpu":
+        return TPU_V5E
+    try:
+        return PEAKS_BY_DEVICE_KIND[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak table entry for TPU device kind {device.device_kind!r}; "
+            f"known: {sorted(PEAKS_BY_DEVICE_KIND)}"
+        ) from None
 
 
 @dataclasses.dataclass(frozen=True)

@@ -5,9 +5,7 @@ ambient mesh (``repro.compat.set_mesh``), silently dropping axis names the
 mesh doesn't have and becoming a no-op when there is no mesh (CPU smoke
 tests). This lets model internals pin the few layouts GSPMD gets wrong
 (split-K decode attention) without threading mesh objects through every
-call. On JAX without abstract meshes the ambient mesh is the physical one,
-and the constraint is issued as a NamedSharding (which needs no resource
-env); on newer JAX the bare PartitionSpec binds to the abstract mesh.
+call. The bare PartitionSpec binds to the ambient abstract mesh.
 """
 
 from __future__ import annotations
@@ -50,9 +48,4 @@ def constrain(x: jax.Array, *entries: AxisEntry) -> jax.Array:
             n *= sizes[a]
         if d % n:
             spec[i] = None
-    pspec = P(*spec)
-    if isinstance(mesh, jax.sharding.Mesh):  # physical-mesh fallback (0.4.x)
-        return jax.lax.with_sharding_constraint(
-            x, jax.sharding.NamedSharding(mesh, pspec)
-        )
-    return jax.lax.with_sharding_constraint(x, pspec)
+    return jax.lax.with_sharding_constraint(x, P(*spec))

@@ -36,13 +36,15 @@ def _scan_kernel(a_ref, b_ref, o_ref, h_ref, *, chunk: int):
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    a = a_ref[...].astype(jnp.float32)  # [chunk, D]
-    b = b_ref[...].astype(jnp.float32)
-
-    def step(t, carry):
-        h = carry
-        h = a[t] * h + b[t]
-        o_ref[t, :] = h.astype(o_ref.dtype)
+    def step(t, h):
+        # Row t is read from the refs (pl.ds), not by indexing a loaded
+        # [chunk, D] value: Mosaic has no dynamic_slice on values.
+        row = pl.ds(t, 1)
+        h = (
+            a_ref[row, :].astype(jnp.float32) * h
+            + b_ref[row, :].astype(jnp.float32)
+        )
+        o_ref[row, :] = h.astype(o_ref.dtype)
         return h
 
     h_ref[...] = jax.lax.fori_loop(0, chunk, step, h_ref[...])
@@ -71,7 +73,7 @@ def opope_chunked_scan(
         ],
         out_specs=pl.BlockSpec((ck, d), lambda j: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((sp, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((d,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("arbitrary",),
         ),

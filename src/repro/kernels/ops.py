@@ -10,14 +10,17 @@ across backends (fp32 accumulation, single final cast — see `ref.py`):
   cannot lower, and as the A/B comparison baseline in benchmarks.
 
 New backends register with :func:`register_backend` (an availability probe
-gates selection). The default ``"auto"`` resolver probes whether the
-compiled Pallas path actually lowers on the current platform — once, lazily,
-cached — so model code is backend-agnostic and a platform where Mosaic is
-absent degrades to ``xla`` instead of raising at the first layer. An
-explicitly requested backend that is unavailable likewise degrades along its
-*fallback chain* (default ``pallas_interpret`` then ``xla``; a registered
-backend may declare its own chain — the quantized backends fall back to
-``xla_q8`` so degradation preserves quantized numerics) rather than raising.
+gates selection). The default ``"auto"`` resolver takes ``pallas`` on a TPU
+and ``xla`` elsewhere, so model code is backend-agnostic. On a TPU the
+compiled path is probed once, lazily: a probe the compiler refuses
+**raises** with the compiler's message — nothing on a TPU quietly runs as a
+plain XLA dot or in the Pallas interpreter. Off the TPU, an explicitly
+requested backend that is unavailable degrades along its *fallback chain*
+(default ``pallas_interpret`` then ``xla``; a registered backend may declare
+its own chain — the quantized backends fall back to ``xla_q8`` so
+degradation preserves quantized numerics) rather than raising. On a TPU the
+``*_interpret`` backends are never a degradation target; they run only when
+named.
 
 Quantized backends (``xla_q8``, ``pallas_q8`` — see :mod:`repro.quant`)
 register themselves on first use: an unknown backend name triggers one lazy
@@ -272,11 +275,26 @@ def family_of(name: str) -> str:
     return b.family
 
 
-def _probe_ok(backend: _Backend) -> bool:
+def _platform() -> str:
+    """Platform of the default device ("tpu", "cpu", ...). Called lazily at
+    resolution time, never at import (see :func:`_pallas_compiles`)."""
+    return jax.devices()[0].platform
+
+
+def _run_probe(probe: Callable[[], bool]) -> bool:
+    """Run an availability probe. Off the TPU a probe that raises counts as
+    "unavailable"; on a TPU the error propagates — a compiler refusal there
+    is a fault to show, not a reason to run somewhere else."""
     try:
-        return bool(backend.available())
+        return bool(probe())
     except Exception:
+        if _platform() == "tpu":
+            raise
         return False
+
+
+def _probe_ok(backend: _Backend) -> bool:
+    return _run_probe(backend.available)
 
 
 def _grouped_ok(backend: _Backend) -> bool:
@@ -285,10 +303,27 @@ def _grouped_ok(backend: _Backend) -> bool:
         return False
     if backend.grouped_available is None:
         return True
+    return _run_probe(backend.grouped_available)
+
+
+def _fallback_target_ok(name: str) -> bool:
+    """Interpreter backends are degradation targets off the TPU only: on a
+    TPU they would run every kernel in the Pallas interpreter on the host.
+    They stay resolvable when requested by name."""
+    return not (name.endswith("_interpret") and _platform() == "tpu")
+
+
+def _compile_probe(what: str, lower) -> bool:
+    """Compile ``lower()`` (a ``jax.stages.Lowered``) as an availability
+    probe on a TPU. A refusal raises with the compiler's message instead of
+    quietly sending every GEMM to another backend."""
     try:
-        return bool(backend.grouped_available())
-    except Exception:
-        return False
+        lower().compile()
+    except Exception as e:
+        raise RuntimeError(
+            f"compiled {what} failed to compile on this TPU: {e}"
+        ) from e
+    return True
 
 
 @functools.lru_cache(maxsize=None)
@@ -297,38 +332,31 @@ def _pallas_compiles() -> bool:
 
     Lazy (first ``auto``/``pallas`` resolution, not import) because touching
     ``jax.devices()`` at import would lock the device count before the
-    dry-run can set ``XLA_FLAGS``. A tiny one-tile GEMM is lowered and
-    compiled; any failure (no TPU, no Mosaic support) means "unavailable".
+    dry-run can set ``XLA_FLAGS``. Off the TPU the answer is "unavailable";
+    on a TPU a tiny one-tile GEMM is lowered and compiled, and a failure
+    raises (:func:`_compile_probe`).
     """
-    try:
-        if jax.devices()[0].platform != "tpu":
-            return False
-        a = jax.ShapeDtypeStruct((8, 128), jnp.float32)
-        b = jax.ShapeDtypeStruct((128, 128), jnp.float32)
-        _kern.opope_gemm.lower(a, b, interpret=False).compile()
-        return True
-    except Exception:
+    if _platform() != "tpu":
         return False
+    a = jax.ShapeDtypeStruct((8, 128), jnp.float32)
+    b = jax.ShapeDtypeStruct((128, 128), jnp.float32)
+    return _compile_probe(
+        "Pallas GEMM", lambda: _kern.opope_gemm.lower(a, b, interpret=False)
+    )
 
 
 @functools.lru_cache(maxsize=None)
 def _pallas_grouped_compiles() -> bool:
-    """Probe once whether the compiled grouped (G, m, n, k) grid lowers here.
-
-    A separate probe from :func:`_pallas_compiles` on purpose: a platform
-    where only the grouped grid fails keeps its compiled 2-D kernels for
-    every dense matmul and degrades ``grouped_matmul`` alone (with the
-    resolver's warning) instead of demoting the whole backend to ``xla``.
-    """
-    try:
-        if not _pallas_compiles():
-            return False
-        ag = jax.ShapeDtypeStruct((2, 8, 128), jnp.float32)
-        bg = jax.ShapeDtypeStruct((2, 128, 128), jnp.float32)
-        _gkern.opope_gemm_grouped.lower(ag, bg, interpret=False).compile()
-        return True
-    except Exception:
+    """Probe once whether the compiled grouped (G, m, n, k) grid lowers here
+    (a separate probe from :func:`_pallas_compiles`, same rules)."""
+    if not _pallas_compiles():
         return False
+    ag = jax.ShapeDtypeStruct((2, 8, 128), jnp.float32)
+    bg = jax.ShapeDtypeStruct((2, 128, 128), jnp.float32)
+    return _compile_probe(
+        "grouped Pallas GEMM",
+        lambda: _gkern.opope_gemm_grouped.lower(ag, bg, interpret=False),
+    )
 
 
 # Cap on the per-(backend, family, M, N, K, G, dtype) tile-selection memo. A
@@ -1009,12 +1037,13 @@ def _load_plugin_backends() -> None:
 def resolve_backend(name: Optional[str] = None) -> str:
     """Resolve a backend request to the name of an available backend.
 
-    ``None`` means the process default; ``"auto"`` picks ``pallas`` when the
-    compiled path lowers here, else ``xla``. An unavailable explicit request
-    degrades along the backend's fallback chain (default
-    ``pallas_interpret`` -> ``xla``) with a warning — but only onto members
-    of the same numerics family: rather than silently change quantization
-    behaviour, resolution raises.
+    ``None`` means the process default; ``"auto"`` picks ``pallas`` on a TPU
+    (whose compile probe raises if Mosaic refuses it), else ``xla``. An
+    unavailable explicit request degrades along the backend's fallback chain
+    (default ``pallas_interpret`` -> ``xla``) with a warning — but only onto
+    members of the same numerics family (rather than silently change
+    quantization behaviour, resolution raises), and on a TPU never onto an
+    ``*_interpret`` backend.
     """
     name = name or _DEFAULT_BACKEND
     if name == "auto":
@@ -1041,6 +1070,7 @@ def resolve_backend(name: Optional[str] = None) -> str:
             fallback != name
             and fb is not None
             and fb.family == backend.family
+            and _fallback_target_ok(fallback)
             and _probe_ok(fb)
         ):
             warnings.warn(
@@ -1076,8 +1106,9 @@ def resolve_grouped_backend(name: Optional[str] = None) -> str:
         if (
             fallback != resolved
             and fb is not None
-            and _grouped_ok(fb)
             and fb.family == backend.family
+            and _fallback_target_ok(fallback)
+            and _grouped_ok(fb)
             and _probe_ok(fb)
         ):
             warnings.warn(

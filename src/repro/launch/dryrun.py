@@ -41,7 +41,7 @@ from repro import compat, obs
 from repro.configs import ARCHS, applicable_shapes, get_config, shape_by_name
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.core.hlo_census import census_hlo
-from repro.core.roofline import TPU_V5E, model_flops, roofline_terms
+from repro.core.roofline import device_peaks, model_flops, roofline_terms
 from repro.distributed import (
     batch_shardings,
     cache_shardings,
@@ -185,7 +185,7 @@ def run_cell(
         flops_dev,
         bytes_dev,
         census.collective_bytes,
-        hw=TPU_V5E,
+        hw=device_peaks(),
         model_flops_total=mf,
         n_chips=n_chips,
     )

@@ -8,20 +8,24 @@ Two engines over the same compiled prefill/decode substrate:
 * ``--engine static`` — the lockstep ``ServeEngine`` baseline: one batch
   enters and exits together.
 
-Reduced configs run real tokens on CPU; production shapes are exercised
-(lowered+compiled) by the dry-run's decode cells.
+Reduced configs run real tokens on CPU; on a TPU the published configs
+serve at full width (``chip_smoke.py`` at the repo root drives this path).
+JAX's persistent compilation cache is placed by
+:mod:`repro.launch.compile_cache`.
 """
 
 from __future__ import annotations
 
 import argparse
 import time
+from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro import obs
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 from repro.serve import (
     ContinuousEngine,
@@ -64,8 +68,8 @@ def _static(cfg, params, args) -> None:
     log.info("first_sequence", tokens=str(toks[0].tolist()))
 
 
-def _continuous(cfg, params, args) -> None:
-    gens = gen_len_spread(args.gen)
+def _continuous(cfg, params, args):
+    gens = (args.gen,) if args.fixed_gen else gen_len_spread(args.gen)
     trace = poisson_trace(
         args.n_requests, seed=args.seed, vocab=cfg.vocab,
         prompt_lens=(args.prompt_len // 4 or 1, args.prompt_len // 2 or 1,
@@ -114,9 +118,12 @@ def _continuous(cfg, params, args) -> None:
         "first_request", uid=first.uid, prompt_tokens=len(first.prompt),
         output=str(report.outputs[first.rid]),
     )
+    return eng, trace, report
 
 
-def main() -> None:
+def main(argv: Optional[List[str]] = None):
+    """Run the serving CLI; the continuous engine returns
+    ``(engine, trace, report)`` (None for the static engine)."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -132,6 +139,9 @@ def main() -> None:
                     help="continuous engine: mean interarrival (decode steps)")
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=32)
+    ap.add_argument("--fixed-gen", action="store_true",
+                    help="continuous engine: every request's budget is "
+                    "exactly --gen (default: a spread up to --gen)")
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--kv-format", default=None,
                     choices=(None, "int8", "fp8_e4m3", "fp8_e5m2"),
@@ -144,7 +154,8 @@ def main() -> None:
                     "/metrics, /requests and /trace can be curled against "
                     "the frozen registry (Ctrl-C/SIGINT ends the linger "
                     "early but still runs the atexit dump hooks)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -159,12 +170,13 @@ def main() -> None:
         )
 
     params = api.init_params(cfg, jax.random.key(args.seed))
+    result = None
     if args.engine == "static" or cfg.family in ("audio", "vlm"):
         if args.engine == "continuous":
             log.info("engine_fallback", family=cfg.family, engine="static")
         _static(cfg, params, args)
     else:
-        _continuous(cfg, params, args)
+        result = _continuous(cfg, params, args)
 
     if args.linger_seconds > 0 and server is not None:
         # The run is done and nothing mutates the registry anymore: what
@@ -177,6 +189,7 @@ def main() -> None:
             time.sleep(args.linger_seconds)
         except KeyboardInterrupt:
             pass
+    return result
 
 
 if __name__ == "__main__":

@@ -16,6 +16,7 @@ import jax
 from repro import obs
 from repro.configs import get_config
 from repro.data import MarkovLMDataset, make_batch_fn
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import AdamWConfig
 from repro.train import TrainLoopConfig, train
 
@@ -32,6 +33,7 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=100)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

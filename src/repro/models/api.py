@@ -40,7 +40,15 @@ __all__ = [
 ]
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def init_params(cfg: ArchConfig, key: jax.Array):
+    """Random parameters for ``cfg`` from ``key``.
+
+    Jitted with ``cfg`` static so XLA fuses each weight's draw, scale and
+    cast: run eagerly, every stacked full-depth weight would first exist as
+    an f32 draw and again as its scaled f32 copy before the cast to the
+    parameter dtype, which does not fit one chip's HBM at 6B scale.
+    """
     if cfg.family == "audio":
         return encdec_mod.init_encdec_params(cfg, key)
     if cfg.family == "vlm":

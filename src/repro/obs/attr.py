@@ -16,7 +16,8 @@ so attribution works at the granularity a real wall-clock bracket exists:
 2. :func:`aggregate` folds the records into a :class:`StepWorkload`:
    per-(backend, family, shape-bucket, tile-source) FLOP/byte totals costed
    with :mod:`repro.core.roofline` (``gemm_bytes`` at honest widths, the
-   same TPU-v5e reference the benches report against).
+   peaks of the device the process runs on; CPU runs score against the
+   TPU-v5e reference).
 3. Each subsequent execution of that compiled step calls
    :func:`observe_step` with its measured wall seconds. The step time is
    attributed to the workload entries in proportion to their roofline-bound
@@ -36,7 +37,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from repro.core.roofline import HardwareSpec, TPU_V5E, gemm_bytes
+from repro.core.roofline import HardwareSpec, device_peaks, gemm_bytes
 
 from . import metrics as _metrics
 
@@ -185,9 +186,12 @@ class capture_gemms:
 
 
 def aggregate(
-    records: Sequence[GemmRecord], *, hw: HardwareSpec = TPU_V5E
+    records: Sequence[GemmRecord], *, hw: Optional[HardwareSpec] = None
 ) -> StepWorkload:
-    """Fold captured records into per-class cost totals (roofline-costed)."""
+    """Fold captured records into per-class cost totals, roofline-costed
+    against ``hw`` (default: the peaks of the device this process runs on,
+    :func:`repro.core.roofline.device_peaks`)."""
+    hw = hw or device_peaks()
     workload: StepWorkload = {}
     for rec in records:
         bucket = shape_bucket(rec)

@@ -10,10 +10,10 @@ accumulator, single final cast):
   everywhere.
 * ``pallas_q8`` — the O-POPE kernel with int8 operand streams and an int32
   resident accumulator (:mod:`repro.quant.pallas_q8`): same outer-product
-  dataflow, a quarter of the fp32 path's operand traffic. Degrades to
-  ``pallas_q8_interpret`` (same body, CPU interpreter) and then ``xla_q8`` —
-  never to a full-precision path, so a degraded quantized request keeps
-  quantized numerics.
+  dataflow, a quarter of the fp32 path's operand traffic. Off the TPU it
+  degrades to ``pallas_q8_interpret`` (same body, CPU interpreter) and then
+  ``xla_q8`` — never to a full-precision path, so a degraded quantized
+  request keeps quantized numerics. On a TPU a compile refusal raises.
 
 Because int32 accumulation of int8 products is exact (no reassociation
 error), ``xla_q8`` and ``pallas_q8`` agree bit-for-bit on the accumulator and
@@ -164,36 +164,36 @@ def _pallas_q8_grouped_fn(interpret: bool):
 
 @functools.lru_cache(maxsize=None)
 def _pallas_q8_compiles() -> bool:
-    """Probe once whether the compiled int8 Pallas path lowers here."""
-    try:
-        if jax.devices()[0].platform != "tpu":
-            return False
-        a = jnp.zeros((32, 128), jnp.int8)
-        sa = jnp.ones((32, 1), jnp.float32)
-        b = jnp.zeros((128, 128), jnp.int8)
-        sb = jnp.ones((1, 128), jnp.float32)
-        opope_gemm_q8.lower(a, sa, b, sb, interpret=False).compile()
-        return True
-    except Exception:
+    """Probe once whether the compiled int8 Pallas path lowers here (same
+    rules as ``ops._pallas_compiles``: off the TPU "unavailable", on a TPU
+    a compiler refusal raises)."""
+    if ops._platform() != "tpu":
         return False
+    a = jax.ShapeDtypeStruct((32, 128), jnp.int8)
+    sa = jax.ShapeDtypeStruct((32, 1), jnp.float32)
+    b = jax.ShapeDtypeStruct((128, 128), jnp.int8)
+    sb = jax.ShapeDtypeStruct((1, 128), jnp.float32)
+    return ops._compile_probe(
+        "int8 Pallas GEMM",
+        lambda: opope_gemm_q8.lower(a, sa, b, sb, interpret=False),
+    )
 
 
 @functools.lru_cache(maxsize=None)
 def _pallas_q8_grouped_compiles() -> bool:
     """Probe the compiled grouped int8 grid separately (per-member
-    availability): a grouped-only lowering failure degrades grouped_matmul
-    along the q8 chain without demoting the 2-D pallas_q8 member."""
-    try:
-        if not _pallas_q8_compiles():
-            return False
-        ag = jnp.zeros((2, 32, 128), jnp.int8)
-        sag = jnp.ones((2, 32, 1), jnp.float32)
-        bg = jnp.zeros((2, 128, 128), jnp.int8)
-        sbg = jnp.ones((2, 1, 128), jnp.float32)
-        opope_gemm_q8_grouped.lower(ag, sag, bg, sbg, interpret=False).compile()
-        return True
-    except Exception:
+    availability): off the TPU a grouped-only failure degrades
+    grouped_matmul along the q8 chain without demoting the 2-D member."""
+    if not _pallas_q8_compiles():
         return False
+    ag = jax.ShapeDtypeStruct((2, 32, 128), jnp.int8)
+    sag = jax.ShapeDtypeStruct((2, 32, 1), jnp.float32)
+    bg = jax.ShapeDtypeStruct((2, 128, 128), jnp.int8)
+    sbg = jax.ShapeDtypeStruct((2, 1, 128), jnp.float32)
+    return ops._compile_probe(
+        "grouped int8 Pallas GEMM",
+        lambda: opope_gemm_q8_grouped.lower(ag, sag, bg, sbg, interpret=False),
+    )
 
 
 def register_quant_backends() -> None:

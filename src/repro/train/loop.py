@@ -174,6 +174,7 @@ def train(
     # estimate (core.roofline.model_flops), so each step event carries
     # achieved GFLOP/s and its fraction of the reference roofline.
     n_params = sum(int(x.size) for x in jax.tree.leaves(params))
+    peak_flops = _roofline.device_peaks().peak_flops
     try:
         for step in range(start, loop.total_steps):
             if loop.fail_at_step is not None and step == loop.fail_at_step:
@@ -200,8 +201,7 @@ def train(
                     tokens=tokens,
                     tokens_per_sec=tok_s,
                     gflops_per_sec=gflops,
-                    roofline_frac=flops / dt / _roofline.TPU_V5E.peak_flops
-                    if dt else 0.0,
+                    roofline_frac=flops / dt / peak_flops if dt else 0.0,
                 )
             if wd.observe(dt):
                 log(f"[train] straggler: step {step} took {dt:.3f}s")

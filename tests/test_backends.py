@@ -1,10 +1,13 @@
 """Backend registry degradation chain: explicit-request fallback
 ``pallas -> pallas_interpret -> xla`` with the RuntimeWarning contract, plus
 ``set_default_backend("auto")`` round-trips. Probes are monkeypatched so the
-chain is exercised deterministically regardless of the host platform.
+chain is exercised deterministically regardless of the host platform; the
+TPU rules (a refused compile raises, no interpreter fallback) are exercised
+by monkeypatching the platform.
 """
 
 import dataclasses
+import warnings
 
 import jax.numpy as jnp
 import numpy as np
@@ -104,3 +107,90 @@ def test_unknown_backend_rejected():
 def test_register_backend_requires_callable():
     with pytest.raises(TypeError):
         ops.register_backend("broken", fn=None)
+
+
+# ---------------------------------------------------------------------------
+# On a TPU nothing falls back off the compiled path (platform monkeypatched)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Resolution as it runs on a TPU: the platform reads "tpu" and the
+    memoized compile probes start (and end) cold."""
+    from repro.quant import backends as qb
+
+    probes = (
+        ops._pallas_compiles, ops._pallas_grouped_compiles,
+        qb._pallas_q8_compiles, qb._pallas_q8_grouped_compiles,
+    )
+    for p in probes:
+        p.cache_clear()
+    monkeypatch.setattr(ops, "_platform", lambda: "tpu")
+    yield
+    for p in probes:
+        p.cache_clear()
+
+
+def test_tpu_failing_compile_probe_raises_with_compiler_message(on_tpu):
+    # This host has no Mosaic: the real compile of the probe GEMM fails,
+    # which on a "tpu" platform must surface instead of resolving to xla.
+    with pytest.raises(RuntimeError, match="failed to compile on this TPU") as e:
+        ops.resolve_backend("auto")
+    assert e.value.__cause__ is not None  # the compiler's own error
+    with pytest.raises(RuntimeError, match="failed to compile on this TPU"):
+        ops.resolve_backend("pallas")
+    with pytest.raises(RuntimeError, match="int8 Pallas GEMM failed"):
+        ops.resolve_backend("pallas_q8")
+
+
+def test_tpu_raising_probe_propagates(on_tpu, monkeypatch):
+    def boom():
+        raise OSError("probe exploded")
+
+    b = ops._REGISTRY["pallas"]
+    monkeypatch.setitem(
+        ops._REGISTRY, "pallas", dataclasses.replace(b, available=boom)
+    )
+    with pytest.raises(OSError, match="probe exploded"):
+        ops.resolve_backend("pallas")
+
+
+def test_tpu_never_degrades_onto_an_interpreter(on_tpu, monkeypatch):
+    _force_unavailable(monkeypatch, "pallas")
+    with pytest.warns(RuntimeWarning, match="degrading to 'xla'"):
+        assert ops.resolve_backend("pallas") == "xla"
+    with pytest.warns(RuntimeWarning, match="degrading to 'xla'"):
+        assert ops.resolve_grouped_backend("pallas") == "xla"
+    _force_unavailable(monkeypatch, "pallas_q8")
+    with pytest.warns(RuntimeWarning, match="degrading to 'xla_q8'"):
+        assert ops.resolve_backend("pallas_q8") == "xla_q8"
+    # named explicitly, the interpreters still resolve
+    assert ops.resolve_backend("pallas_interpret") == "pallas_interpret"
+    assert ops.resolve_backend("pallas_q8_interpret") == "pallas_q8_interpret"
+
+
+def test_tpu_auto_takes_compiled_pallas(on_tpu, monkeypatch):
+    _force_available(monkeypatch, "pallas")
+    assert ops.resolve_backend("auto") == "pallas"
+
+
+@pytest.mark.parametrize(
+    "requested,resolved",
+    [
+        ("auto", "xla"),
+        ("pallas", "pallas_interpret"),
+        ("pallas_interpret", "pallas_interpret"),
+        ("xla", "xla"),
+        ("pallas_q8", "pallas_q8_interpret"),
+        ("pallas_q8_interpret", "pallas_q8_interpret"),
+        ("xla_q8", "xla_q8"),
+    ],
+)
+def test_cpu_resolution_unchanged(requested, resolved):
+    """Off the TPU the real probes answer "unavailable" without raising and
+    the fallback chains degrade as they always have."""
+    assert ops._platform() != "tpu"
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)
+        assert ops.resolve_backend(requested) == resolved

@@ -1,0 +1,214 @@
+"""Compile the main path's kernels for a described TPU v5e, at chatglm3-6b
+widths.
+
+Nothing runs: the TPU compiler installed beside JAX compiles for a chip that
+is described, not attached, and refuses what Mosaic would refuse on the
+chip (unaligned slices, primitives it cannot lower, VMEM overruns, programs
+that do not fit HBM). Each test asserts that the compiled program holds a
+Mosaic kernel (``tpu_custom_call``).
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, and every test worker
+imports this file. The persistent compilation cache is off around these
+compiles (an entry compiled for a described chip cannot be read back here).
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels import ops
+
+GiB = 1 << 30
+CFG = get_config("chatglm3-6b")
+D, FF = CFG.d_model, CFG.d_ff
+QKV_N = (CFG.n_heads + 2 * CFG.n_kv) * CFG.head_dim_
+DECODE_M, PREFILL_M = 4, 2048  # 4 decode slots; a 4 x 512 prompt bucket
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        jax.config.update("jax_enable_compilation_cache", was)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def compiled_pallas(monkeypatch):
+    """Steer ``pallas`` to the compiled kernels on this CPU host: its probe
+    answers "available", so the registry lowers Mosaic kernels instead of
+    degrading to the interpreter."""
+    import dataclasses
+
+    b = ops._REGISTRY["pallas"]
+    monkeypatch.setitem(
+        ops._REGISTRY, "pallas",
+        dataclasses.replace(
+            b, available=lambda: True, grouped_available=lambda: True
+        ),
+    )
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *specs):
+    return jax.jit(fn).lower(*specs).compile()
+
+
+def _assert_kernel(compiled, at_least=1):
+    n = compiled.as_text().count("tpu_custom_call")
+    assert n >= at_least, f"{n} Mosaic kernels in the compiled program"
+
+
+# (name, K, N, epilogue kind): the chatglm3-6b GEMMs of one layer.
+GEMMS = [
+    ("qkv", D, QKV_N, None),
+    ("qkv_bias", D, QKV_N, "bias"),
+    ("mlp_gate_silu_mul", D, FF, "silu_mul"),
+    ("mlp_down_residual", FF, D, "residual"),
+    ("attn_out_residual", D, D, "residual"),
+]
+
+
+@pytest.mark.parametrize("m", [DECODE_M, PREFILL_M], ids=["decode", "prefill"])
+@pytest.mark.parametrize("name,k,n,ep", GEMMS, ids=[g[0] for g in GEMMS])
+def test_gemm_compiles(one_chip, compiled_pallas, m, name, k, n, ep):
+    a, b = _spec((m, k), BF16, one_chip), _spec((k, n), BF16, one_chip)
+    if ep is None:
+        fn, specs = (lambda a, b: ops.matmul(a, b, backend="pallas")), (a, b)
+    elif ep == "bias":
+        fn = lambda a, b, c: ops.matmul(a, b, c, backend="pallas")  # noqa: E731
+        specs = (a, b, _spec((n,), BF16, one_chip))
+    elif ep == "silu_mul":
+        fn = lambda a, b, up: ops.matmul(  # noqa: E731
+            a, b, backend="pallas", epilogue=["silu", ("mul", up)])
+        specs = (a, b, _spec((m, n), BF16, one_chip))
+    else:
+        fn = lambda a, b, r: ops.matmul(  # noqa: E731
+            a, b, backend="pallas", epilogue=[("residual", r)])
+        specs = (a, b, _spec((m, n), BF16, one_chip))
+    _assert_kernel(_compile(fn, *specs))
+
+
+@pytest.mark.parametrize("m", [DECODE_M, PREFILL_M], ids=["decode", "prefill"])
+def test_backward_gemms_compile(one_chip, compiled_pallas, m):
+    """The custom_vjp backward of the MLP up projection: dA = dO @ B^T
+    ([M, FF] @ [FF, D]) and dB = A^T @ dO ([D, M] @ [M, FF])."""
+
+    def loss(a, b):
+        return ops.matmul(a, b, backend="pallas").astype(jnp.float32).sum()
+
+    grad = jax.grad(loss, argnums=(0, 1))
+    compiled = _compile(
+        grad, _spec((m, D), BF16, one_chip), _spec((D, FF), BF16, one_chip)
+    )
+    # jax.grad drops the forward GEMM (only the gradients are returned)
+    _assert_kernel(compiled, at_least=2)
+
+
+def test_grouped_gemm_compiles(one_chip):
+    from repro.kernels.opope_grouped import opope_gemm_grouped
+
+    fn = lambda a, b: opope_gemm_grouped(a, b, interpret=False)  # noqa: E731
+    _assert_kernel(_compile(
+        fn, _spec((4, 256, D), BF16, one_chip), _spec((4, D, 1024), BF16, one_chip)
+    ))
+
+
+def test_q8_gemm_compiles(one_chip):
+    from repro.quant.pallas_q8 import opope_gemm_q8
+
+    fn = lambda a, sa, b, sb: opope_gemm_q8(  # noqa: E731
+        a, sa, b, sb, block_m=32, block_n=256, block_k=512, interpret=False)
+    _assert_kernel(_compile(
+        fn,
+        _spec((32, D), jnp.int8, one_chip),
+        _spec((32, 1), jnp.float32, one_chip),
+        _spec((D, QKV_N), jnp.int8, one_chip),
+        _spec((1, QKV_N), jnp.float32, one_chip),
+    ))
+
+
+@pytest.mark.parametrize("s,t", [(512, 512), (1, 512)], ids=["prefill", "decode"])
+def test_attention_compiles(one_chip, s, t):
+    from repro.kernels.opope_attention import opope_attention
+
+    hd = CFG.head_dim_
+    fn = lambda q, k, v: opope_attention(q, k, v, interpret=False)  # noqa: E731
+    _assert_kernel(_compile(
+        fn, _spec((s, hd), BF16, one_chip), _spec((t, hd), BF16, one_chip),
+        _spec((t, hd), BF16, one_chip),
+    ))
+
+
+def test_chunked_scan_compiles(one_chip):
+    from repro.kernels.opope_scan import opope_chunked_scan
+
+    fn = lambda a, b: opope_chunked_scan(a, b, interpret=False)  # noqa: E731
+    _assert_kernel(_compile(
+        fn, _spec((512, D), jnp.float32, one_chip),
+        _spec((512, D), jnp.float32, one_chip),
+    ))
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_chatglm3_step_compiles_and_fits(one_chip, compiled_pallas, step):
+    """The whole 28-layer chatglm3-6b serving step, as the continuous engine
+    runs it (4 slots; a 4 x 512 prefill bucket), on the compiled kernels:
+    parameters plus temporaries fit one v5e's 16 GiB."""
+    from repro.models import api
+
+    assert ops.resolve_backend("auto") == "pallas"
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _spec(x.shape, x.dtype, one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        functools.partial(api.init_params, CFG), jax.random.key(0)))
+    i32 = jnp.int32
+    if step == "decode":
+        caches = on_chip(jax.eval_shape(
+            lambda: api.init_state(CFG, 4, 2048, jnp.bfloat16)))
+        fn = jax.jit(
+            lambda p, c, t, pos: api.decode_at(CFG, p, t, c, pos),
+            donate_argnums=(1,),
+        )
+        specs = (params, caches, _spec((4, 1), i32, one_chip),
+                 _spec((4,), i32, one_chip))
+    else:
+        fn = jax.jit(lambda p, t, n: api.prefill_bucketed(CFG, p, t, n))
+        specs = (params, _spec((4, 512), i32, one_chip),
+                 _spec((4,), i32, one_chip))
+    compiled = fn.lower(*specs).compile()
+    _assert_kernel(compiled, at_least=4)
+    ma = compiled.memory_analysis()
+    need = ma.argument_size_in_bytes + ma.temp_size_in_bytes
+    assert 12 * GiB > ma.argument_size_in_bytes > 11 * GiB  # full width, bf16
+    assert need < 16 * GiB, f"{need / GiB:.2f} GiB > 16 GiB"
