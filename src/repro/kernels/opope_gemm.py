@@ -301,6 +301,7 @@ def opope_gemm(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="opope_gemm",  # the kernel's op name in a profile
     )(*operands)
     return out[:m, :n]
 

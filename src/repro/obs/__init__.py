@@ -10,9 +10,9 @@ own numbers. This package is the one place runtime observability lives:
   host-side Python (trace-time inside ``jit``), so telemetry adds **zero
   ops to compiled HLO** on or off — asserted on a jitted decode step by
   ``tests/test_obs.py``.
-* :mod:`~repro.obs.spans` — ``span(name)``: ``jax.profiler.TraceAnnotation``
-  + ``jax.named_scope`` on the device side, a wall-clock histogram on the
-  host side.
+* :mod:`~repro.obs.spans` — ``span(name)``: a ``jax.profiler.TraceAnnotation``
+  on the profiler's clock (the serving engine's ``serve.*`` phases) and a
+  wall-clock histogram.
 * :mod:`~repro.obs.logging` — structured launch-script logging
   (``REPRO_LOG=text|json``) and the JSONL event log (``REPRO_EVENTS``,
   ``repro-stats tail``) the train loop's per-step records flow through.

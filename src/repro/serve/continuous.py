@@ -52,6 +52,16 @@ share the TTFT stamps, so the exported Perfetto timeline decomposes each
 ``serve.ttft_seconds`` sample exactly. ``slo_ttft_ms``/``slo_itl_ms`` (or
 ``REPRO_SLO_TTFT_MS``/``REPRO_SLO_ITL_MS``) turn those stamps into
 ``ServingReport.goodput``.
+
+Each phase of a tick runs inside an ``obs.span``: ``serve.admit``
+(scheduling and admission stamps), ``serve.prefill`` (one monolithic join),
+``serve.chunk`` (one chunk-pipeline advance and its completion),
+``serve.decode`` (the step's dispatch), ``serve.readback`` (the wait for the
+step's tokens), ``serve.emit`` (``on_token`` and retirement) and
+``serve.telemetry``. They are siblings, never nested, and land on the
+profiler's clock, so any ``jax.profiler`` capture of a serving process names
+what the host was doing in each idle gap of the device. The deferred path
+has no readback or emit.
 """
 
 from __future__ import annotations
@@ -397,21 +407,26 @@ class ContinuousEngine:
         # across serve() calls, so the peak does too.
         self._prefix_hwm = 0
 
-        @functools.partial(jax.jit, static_argnums=())
-        def _prefill(params, tokens, lengths):
+        # The step programs' names are part of the profile's vocabulary:
+        # a trace shows them as ``jit_engine_prefill``, ``jit_engine_chunk``
+        # and ``jit_engine_decode``, and bench/lib/trace.py tells the three
+        # kinds apart by ``_prefill``, ``_chunk`` and ``_decode`` in them.
+        # No other program the engine dispatches carries those tokens.
+        @jax.jit
+        def engine_prefill(params, tokens, lengths):
             logits, caches = model_api.prefill_bucketed(
                 cfg, params, tokens, lengths, self.cache_dtype
             )
             return logits, caches
 
         @functools.partial(jax.jit, donate_argnums=(1,))
-        def _chunk(params, caches, ctoks, offsets, last_idx):
+        def engine_chunk(params, caches, ctoks, offsets, last_idx):
             return model_api.prefill_chunk(
                 cfg, params, ctoks, caches, offsets, last_idx
             )
 
         @functools.partial(jax.jit, donate_argnums=(1,))
-        def _decode(params, caches, tok, pos, active, key):
+        def engine_decode(params, caches, tok, pos, active, key):
             logits, caches = model_api.decode_at(cfg, params, tok, caches, pos)
             nxt = sample_token(logits, key, self.temperature)
             # Masked slots cost a lane, not a recompile: they hold token and
@@ -420,15 +435,15 @@ class ContinuousEngine:
             pos = pos + active.astype(jnp.int32)
             return nxt, caches, pos
 
-        self._prefill = _prefill
-        self._chunk = _chunk
-        self._decode = _decode
+        self._prefill = engine_prefill
+        self._chunk = engine_chunk
+        self._decode = engine_decode
         # Utilization-attribution state (obs.attr): the GEMM workload of each
         # compiled step, captured once at trace time, then charged with every
         # subsequent dispatch's measured wall time. Keyed per compiled
-        # program: one decode step; prefills per (rows, bucket); chunk steps
-        # per (rows, bucket, width).
-        self._decode_workload = None
+        # program: one decode step; chunk steps per (rows, bucket, width).
+        # Monolithic prefills are not charged: the host sees only their
+        # async enqueue, not their execution.
         self._prefill_workloads: Dict[tuple, dict] = {}
 
     # -- introspection -----------------------------------------------------
@@ -569,9 +584,8 @@ class ContinuousEngine:
                 n_arrival_stamped += 1
 
             # -- join: refill free slots from the queue ---------------------
-            joined = False
             chunked = self.prefill_chunk is not None
-            while pool.n_free:
+            while pool.n_free and len(sched):
                 admissible = None
                 if chunked and len(self._pending) >= _MAX_PENDING:
                     # Pipeline full: only prompts whose remaining prefill
@@ -582,23 +596,27 @@ class ContinuousEngine:
                     admissible = (
                         lambda r: self._suffix_len(r) <= self.prefill_chunk
                     )
-                batch = sched.next_batch(
-                    pool.n_free, now=step, admissible=admissible
-                )
+                with _obs.span("serve.admit"):
+                    batch = sched.next_batch(
+                        pool.n_free, now=step, admissible=admissible
+                    )
+                    if batch:
+                        adm = sched.last_admission or {}
+                        lc.admitted(
+                            batch, wall(), adm.get("bucket"),
+                            bool(adm.get("fallthrough")),
+                            phase="prefix_attach" if chunked else "prefill",
+                        )
+                        if chunked:
+                            pj = self._begin_join(sched, pool, batch, step)
+                            lc.attached(batch, wall())
                 if not batch:
                     break
-                adm = sched.last_admission or {}
                 if self.temperature > 0:
                     key, sub = jax.random.split(key)
                 else:
                     sub = key  # greedy: sampling ignores the key
                 if chunked:
-                    lc.admitted(
-                        batch, wall(), adm.get("bucket"),
-                        bool(adm.get("fallthrough")), phase="prefix_attach",
-                    )
-                    pj = self._begin_join(sched, pool, batch, step)
-                    lc.attached(batch, wall())
                     if len(self._pending) < _MAX_PENDING:
                         self._pending.append(pj)  # advances below, this tick
                     else:
@@ -606,52 +624,61 @@ class ContinuousEngine:
                         # chunk finishes the whole prompt set — join now.
                         # (Loop, not a single advance: a trie eviction racing
                         # the admissibility check can lengthen a suffix.)
-                        while not pj.all_done:
-                            self._advance_chunk(pj)
-                        tok, pos, n_gen = self._complete_join(
-                            pj, sched, pool, tok, pos, active, sub, step,
-                            on_token, sync, pending,
-                        )
+                        with _obs.span(
+                            "serve.chunk",
+                            args=_join_args(pj.batch, pj.rows, pj.lb),
+                        ):
+                            while not pj.all_done:
+                                self._advance_chunk(pj)
+                            tok, pos, n_gen = self._complete_join(
+                                pj, sched, pool, tok, pos, active, sub, step,
+                                on_token, sync, pending,
+                            )
+                            self._stamp_join(pj.batch, sched, wall, lc)
+                            active_dev = jnp.asarray(active)
                         prefill_batches += 1
                         generated += n_gen
-                        joined = True
-                        self._stamp_join(pj.batch, sched, wall, lc)
                 else:
-                    lc.admitted(
-                        batch, wall(), adm.get("bucket"),
-                        bool(adm.get("fallthrough")), phase="prefill",
-                    )
-                    tok, pos, active, n_gen = self._join(
-                        sched, pool, batch, tok, pos, active, sub, step,
-                        on_token, sync, pending,
-                    )
+                    with _obs.span(
+                        "serve.prefill",
+                        args=_join_args(
+                            batch, _pow2(len(batch)), adm["bucket"]
+                        ),
+                    ):
+                        tok, pos, active, n_gen = self._join(
+                            sched, pool, batch, tok, pos, active, sub, step,
+                            on_token, sync, pending,
+                        )
+                        # First token exists now (sampled from prefill
+                        # logits): the join stamp closes each request's TTFT
+                        # window.
+                        self._stamp_join(batch, sched, wall, lc)
+                        active_dev = jnp.asarray(active)
                     prefill_batches += 1
                     generated += n_gen  # one token per request, prefill logits
-                    joined = True
-                    # First token exists now (sampled from prefill logits):
-                    # the join stamp closes each request's TTFT window.
-                    self._stamp_join(batch, sched, wall, lc)
 
             # -- advance the pending chunk pipeline by one chunk ------------
             if self._pending:
                 pj = self._pending[0]
-                self._advance_chunk(pj)
+                with _obs.span(
+                    "serve.chunk", args=_join_args(pj.batch, pj.rows, pj.lb)
+                ):
+                    self._advance_chunk(pj)
+                    if pj.all_done:
+                        if self.temperature > 0:
+                            key, sub = jax.random.split(key)
+                        else:
+                            sub = key
+                        tok, pos, n_gen = self._complete_join(
+                            pj, sched, pool, tok, pos, active, sub, step,
+                            on_token, sync, pending,
+                        )
+                        self._stamp_join(pj.batch, sched, wall, lc)
+                        active_dev = jnp.asarray(active)
                 if pj.all_done:
-                    if self.temperature > 0:
-                        key, sub = jax.random.split(key)
-                    else:
-                        sub = key
-                    tok, pos, n_gen = self._complete_join(
-                        pj, sched, pool, tok, pos, active, sub, step,
-                        on_token, sync, pending,
-                    )
                     prefill_batches += 1
                     generated += n_gen
-                    joined = True
-                    self._stamp_join(pj.batch, sched, wall, lc)
                     self._pending.pop(0)
-            if joined:
-                active_dev = jnp.asarray(active)
 
             if not any(active):
                 if sched.drained and not self._pending:
@@ -662,19 +689,20 @@ class ContinuousEngine:
             # -- decode: one fused masked step over the whole pool ----------
             t_step = wall()
             n_live = sum(active)
-            if self.temperature > 0:
-                key, sub = jax.random.split(key)
-            else:
-                sub = key
-            with _attr.capture_gemms() as step_recs:
-                tok, pool.caches, pos = self._decode(
-                    self.params, pool.caches, tok, pos, active_dev, sub
+            with _obs.span("serve.decode"):
+                if self.temperature > 0:
+                    key, sub = jax.random.split(key)
+                else:
+                    sub = key
+                with _attr.capture_gemms() as step_recs:
+                    tok, pool.caches, pos = self._decode(
+                        self.params, pool.caches, tok, pos, active_dev, sub
+                    )
+                decode_wl = self._step_workload(
+                    ("decode",), self._decode,
+                    (self.params, pool.caches, tok, pos, active_dev, sub),
+                    step_recs, "decode",
                 )
-            decode_wl = self._step_workload(
-                ("decode",), self._decode,
-                (self.params, pool.caches, tok, pos, active_dev, sub),
-                step_recs, "decode",
-            )
             decode_steps += 1
             occupancy_acc += n_live / self.n_slots
             step += 1
@@ -693,23 +721,30 @@ class ContinuousEngine:
             retired_now: List[tuple] = []  # (Request, reason)
             changed = False
             if sync:
-                emitted = np.asarray(tok[:, 0])
-                for slot, rid in zip(live, live_rids):
-                    t = int(emitted[slot])
-                    if on_token is not None:
-                        on_token(rid, t)
-                    generated += 1
-                    if sched.record_token(rid, t, now=step):
-                        reason = (
-                            "eos"
-                            if self.eos_id is not None and t == self.eos_id
-                            else "budget"
-                        )
-                        retired_now.append((sched.states[rid].request, reason))
-                        if pool.release(slot):
-                            n_retired += 1
-                        active[slot] = False
-                        changed = True
+                with _obs.span("serve.readback"):
+                    emitted = np.asarray(tok[:, 0])
+                with _obs.span("serve.emit"):
+                    for slot, rid in zip(live, live_rids):
+                        t = int(emitted[slot])
+                        if on_token is not None:
+                            on_token(rid, t)
+                        generated += 1
+                        if sched.record_token(rid, t, now=step):
+                            reason = (
+                                "eos"
+                                if self.eos_id is not None
+                                and t == self.eos_id
+                                else "budget"
+                            )
+                            retired_now.append(
+                                (sched.states[rid].request, reason)
+                            )
+                            if pool.release(slot):
+                                n_retired += 1
+                            active[slot] = False
+                            changed = True
+                    if changed:
+                        active_dev = jnp.asarray(active)
             else:
                 pending.append((tok, list(zip(live, live_rids))))
                 for slot, rid in zip(live, live_rids):
@@ -722,29 +757,33 @@ class ContinuousEngine:
                             n_retired += 1
                         active[slot] = False
                         changed = True
-            if changed:
-                active_dev = jnp.asarray(active)
+                if changed:
+                    active_dev = jnp.asarray(active)
 
             # Per-tick telemetry: step wall time, each live lane's
             # inter-token gap, queue/occupancy gauges. Retirement stamps
             # come after the token stamps so a request's last ITL instant
             # lands inside its span.
             now = wall()
-            _obs.histogram("serve.step_seconds").observe(now - t_step)
-            if decode_wl:
-                # Same host-wall caveat as ITL: on the deferred path this is
-                # dispatch cadence, on the sync path token-to-token time.
-                _attr.observe_step(decode_wl, now - t_step)
-            for rid in live_rids:
-                lc.token(sched.states[rid].request, now)
-            for r, reason in retired_now:
-                lc.retired(r, sched.states[r.rid], reason, now)
-            _obs.counter("serve.tokens").inc(len(live_rids))
-            if n_retired:
-                _obs.counter("serve.requests", event="retired").inc(n_retired)
-            _obs.gauge("serve.queue_depth").set(sched.n_arrived(step))
-            _obs.gauge("serve.occupancy").set(n_live / self.n_slots)
-            _obs.gauge("serve.slot_pool_hwm").set(pool.leased_hwm)
+            with _obs.span("serve.telemetry"):
+                _obs.histogram("serve.step_seconds").observe(now - t_step)
+                if decode_wl:
+                    # Same host-wall caveat as ITL: on the deferred path this
+                    # is dispatch cadence, on the sync path token-to-token
+                    # time.
+                    _attr.observe_step(decode_wl, now - t_step)
+                for rid in live_rids:
+                    lc.token(sched.states[rid].request, now)
+                for r, reason in retired_now:
+                    lc.retired(r, sched.states[r.rid], reason, now)
+                _obs.counter("serve.tokens").inc(len(live_rids))
+                if n_retired:
+                    _obs.counter("serve.requests", event="retired").inc(
+                        n_retired
+                    )
+                _obs.gauge("serve.queue_depth").set(sched.n_arrived(step))
+                _obs.gauge("serve.occupancy").set(n_live / self.n_slots)
+                _obs.gauge("serve.slot_pool_hwm").set(pool.leased_hwm)
 
         # Deferred fetch: one host sync for the whole run.
         for arr, pairs in pending:
@@ -841,9 +880,7 @@ class ContinuousEngine:
         joins the pool (and its lanes activate) only at completion.
         """
         lb = sched.bucket(max(len(r.prompt) for r in batch))
-        rows = 1
-        while rows < len(batch):
-            rows *= 2
+        rows = _pow2(len(batch))
         plens = np.array([len(r.prompt) for r in batch], np.int64)
         caches = model_api.init_state(
             self.cfg, rows, lb, self.cache_dtype
@@ -1013,11 +1050,9 @@ class ContinuousEngine:
     ):
         """Prefill one bucket, scatter it into leased slots, seed the lanes."""
         lb = sched.bucket(max(len(r.prompt) for r in batch))
-        # Round the row count up to a power of two so prefill compiles stay
-        # bounded per bucket (filler rows duplicate row 0 and scatter-drop).
-        rows = 1
-        while rows < len(batch):
-            rows *= 2
+        # Filler rows (up to the power of two) duplicate row 0 and
+        # scatter-drop.
+        rows = _pow2(len(batch))
         tokens = np.zeros((rows, lb), np.int32)
         lengths = np.ones((rows,), np.int32)
         for i, r in enumerate(batch):
@@ -1027,13 +1062,9 @@ class ContinuousEngine:
             tokens[len(batch):] = tokens[0]
             lengths[len(batch):] = lengths[0]
 
-        args = (self.params, jnp.asarray(tokens), jnp.asarray(lengths))
-        t_pf = time.perf_counter()
-        with _attr.capture_gemms() as pf_recs:
-            logits, caches = self._prefill(*args)
-        wl = self._step_workload((rows, lb), self._prefill, args, pf_recs, "prefill")
-        if wl:
-            _attr.observe_step(wl, time.perf_counter() - t_pf)
+        logits, caches = self._prefill(
+            self.params, jnp.asarray(tokens), jnp.asarray(lengths)
+        )
         first = sample_token(logits, key, self.temperature)
 
         slots = pool.allocate([r.rid for r in batch])
@@ -1064,6 +1095,24 @@ class ContinuousEngine:
                 else:
                     active[slots[i]] = True
         return tok, pos, active, n_gen
+
+
+def _pow2(n: int) -> int:
+    """Rows of a join's prefill: ``n`` rounded up to a power of two, so the
+    prefill and chunk programs compile a bounded number of times per
+    bucket."""
+    rows = 1
+    while rows < n:
+        rows *= 2
+    return rows
+
+
+def _join_args(batch: List[Request], rows: int, bucket) -> Dict[str, int]:
+    """Profiler-annotation arguments of one join's span."""
+    return {
+        "requests": len(batch), "rows": rows, "bucket": int(bucket),
+        "tokens": sum(len(r.prompt) for r in batch),
+    }
 
 
 @functools.partial(jax.jit, donate_argnums=(0,), static_argnums=(3,))

@@ -159,6 +159,27 @@ class TestSpans:
         s = obs.snapshot()["histograms"]["span.seconds"]["name=t.block,phase=x"]
         assert s["count"] == 1 and s["max"] >= 0.0
 
+    def test_span_args_annotate_the_profile_not_the_histogram(self, tmp_path):
+        import glob
+
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with obs.span("t.args", args={"rows": 3}, phase="y"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        assert list(obs.snapshot()["histograms"]["span.seconds"]) == [
+            "name=t.args,phase=y"
+        ]
+        path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+        pd = jax.profiler.ProfileData.from_file(path[0])
+        stats = [
+            dict(e.stats) for p in pd.planes for line in p.lines
+            for e in line.events if e.name == "t.args"
+        ]
+        assert len(stats) == 1
+        assert stats[0]["rows"] == 3 and stats[0]["phase"] == "y"
+
     def test_span_propagates_exceptions_but_still_records(self):
         with pytest.raises(RuntimeError):
             with obs.span("t.boom"):

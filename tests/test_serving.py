@@ -255,6 +255,88 @@ def test_attr_fallback_recaptures_untraced_step():
     assert sum(snap.values()) >= 1
 
 
+def _engine_spans(eng, requests, tmp_path, **kw):
+    """Serve under ``jax.profiler`` and read the trace back through the
+    benchmark's loader: (report, [(start, end, name)] of the ``serve.*``
+    host spans in order, {program name: the kind the reduction gives it})."""
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "bench"))
+    try:
+        from lib import trace
+    finally:
+        sys.path.pop(0)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        report = eng.serve(requests, **kw)
+    finally:
+        jax.profiler.stop_trace()
+    rec = trace.load(trace.find_xplane(str(tmp_path)))
+    spans = [(s, s + d, n) for s, d, n, *_ in rec["host"]
+             if n.startswith("serve.")]
+    names = {m[2].split("(")[0] for m in rec["modules"]}
+    return report, spans, {m: trace.module_kind(m) for m in names}
+
+
+@pytest.mark.parametrize("mode", ["stream", "deferred", "chunked"])
+def test_engine_spans_on_the_profiler_clock(mode, tmp_path):
+    """Each phase of a tick is one sibling span on the profiler's clock.
+    Streaming, every decode tick reads decode -> readback -> emit ->
+    telemetry and a join tick puts admit -> prefill before it; deferred,
+    readback and emit are absent. No engine span encloses another, and only
+    the engine's step programs carry a name the trace reduction maps to a
+    kind."""
+    import re
+
+    cfg = ARCHS["chatglm3-6b"].reduced()
+    params = api.init_params(cfg, KEY)
+    eng = ContinuousEngine(cfg=cfg, params=params, n_slots=2, max_len=48,
+                           cache_dtype=jnp.float32,
+                           prefill_chunk=8 if mode == "chunked" else None)
+    specs = [(7, 4, 0), (12, 3, 0), (9, 3, 0)]
+    kw = {} if mode == "deferred" else {"on_token": lambda rid, tok: None}
+    eng.serve(_trace(cfg, specs), **kw)  # compile outside the profile
+    report, spans, kinds = _engine_spans(
+        eng, _trace(cfg, specs, seed=3), tmp_path, **kw)
+
+    assert all(a[1] <= b[0] for a, b in zip(spans, spans[1:]))  # siblings
+    names = [n.split(".", 1)[1] for _, _, n in spans]
+    assert names.count("decode") == report.decode_steps > 0
+    tick = ("decode,telemetry," if mode == "deferred"
+            else "decode,readback,emit,telemetry,")
+    if mode == "chunked":
+        assert names.count("chunk") >= report.prefill_batches
+        assert "prefill" not in names
+    else:
+        assert re.fullmatch(f"((admit,prefill,)*{tick})+", ",".join(names) + ",")
+        assert (names.count("prefill") == names.count("admit")
+                == report.prefill_batches)
+    step = "jit_engine_chunk" if mode == "chunked" else "jit_engine_prefill"
+    assert {m: k for m, k in kinds.items() if k} == {
+        "jit_engine_decode": "decode", step: step.rsplit("_", 1)[1]}
+
+
+def test_metrics_off_serves_with_bare_spans(tmp_path):
+    """``REPRO_METRICS=0``: the engine serves the same tokens, and its spans
+    are bare (nothing reaches the profile)."""
+    from repro import obs
+
+    cfg = ARCHS["chatglm3-6b"].reduced()
+    params = api.init_params(cfg, KEY)
+    eng = ContinuousEngine(cfg=cfg, params=params, n_slots=2, max_len=48,
+                           cache_dtype=jnp.float32)
+    specs = [(7, 4, 0), (12, 3, 0), (9, 3, 1)]
+    streamed = {}
+    want = eng.serve(_trace(cfg, specs), on_token=lambda rid, tok: None).outputs
+    prev = obs.set_enabled(False)
+    try:
+        report, spans, _ = _engine_spans(
+            eng, _trace(cfg, specs), tmp_path,
+            on_token=lambda rid, tok: streamed.setdefault(rid, []).append(tok))
+    finally:
+        obs.set_enabled(prev)
+    assert spans == []
+    assert report.outputs == want == streamed
+
+
 def test_decode_at_matches_decode_lockstep():
     cfg = ARCHS["qwen2.5-32b"].reduced()  # qkv_bias: bias-preload decode path
     params = api.init_params(cfg, KEY)

@@ -212,3 +212,55 @@ def test_chatglm3_step_compiles_and_fits(one_chip, compiled_pallas, step):
     need = ma.argument_size_in_bytes + ma.temp_size_in_bytes
     assert 12 * GiB > ma.argument_size_in_bytes > 11 * GiB  # full width, bf16
     assert need < 16 * GiB, f"{need / GiB:.2f} GiB > 16 GiB"
+
+
+def test_engine_program_and_kernel_names(one_chip, compiled_pallas):
+    """The names a profile shows, which the benchmark's trace reduction
+    (``bench/lib/trace.py``) keys on: the engine's compiled step programs
+    map to their kinds, their GEMM kernels are named ``opope_gemm``, and the
+    pool's join scatter maps to no kind. Two chatglm3-6b layers at published
+    widths."""
+    import dataclasses
+    import re
+    import sys
+
+    from repro.models import api
+    from repro.serve import ContinuousEngine
+    from repro.serve.cache import init_slot_caches, scatter_slots
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "bench"))
+    try:
+        from lib import trace
+    finally:
+        sys.path.pop(0)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _spec(x.shape, x.dtype, one_chip), tree)
+
+    cfg = dataclasses.replace(CFG, n_layers=2)
+    params = on_chip(jax.eval_shape(
+        functools.partial(api.init_params, cfg), jax.random.key(0)))
+    eng = ContinuousEngine(cfg=cfg, params=params, n_slots=4, max_len=256)
+    pool = on_chip(jax.eval_shape(
+        lambda: init_slot_caches(cfg, 4, 256, eng.cache_dtype)))
+    prompt = on_chip(jax.eval_shape(
+        lambda: api.init_state(cfg, 2, 128, eng.cache_dtype)))
+    i32 = jnp.int32
+    programs = {
+        "decode": eng._decode.lower(
+            params, pool, _spec((4, 1), i32, one_chip), _spec((4,), i32, one_chip),
+            _spec((4,), jnp.bool_, one_chip),
+            on_chip(jax.eval_shape(lambda: jax.random.key(0)))),
+        "prefill": eng._prefill.lower(
+            params, _spec((2, 128), i32, one_chip), _spec((2,), i32, one_chip)),
+        None: scatter_slots.lower(pool, prompt, _spec((2,), i32, one_chip), None),
+    }
+    custom_call = re.compile(
+        r'^\s*(?:ROOT )?%?([\w.\-]+) = .*custom_call_target="tpu_custom_call"', re.M)
+    for kind, lowered in programs.items():
+        text = lowered.compile().as_text()
+        module = re.match(r"HloModule (\S+?),", text).group(1)
+        assert trace.module_kind(module) == kind, module
+        kernels = custom_call.findall(text)
+        if kind is not None:
+            assert kernels and all(k.startswith(trace.GEMM_KERNEL) for k in kernels)
