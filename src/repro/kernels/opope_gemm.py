@@ -27,6 +27,11 @@ Block shapes are multiples of the TPU tile (8x128 lanes; 128-aligned MXU
 dims). Shape padding is applied outside the ``pallas_call`` and reported via
 :func:`padding_waste` — the software analogue of the paper's tile-quantization
 utilization loss (§III-C).
+
+:func:`opope_gemm_stacked` is the serving entry for a layer's weight inside a
+stacked ``[L, K, N]`` parameter: a scalar-prefetched layer index picks the B
+panels straight out of the stack, and the ragged K and N edges are handled
+inside the kernel, so no slice or pad of the weight is ever written to HBM.
 """
 
 from __future__ import annotations
@@ -45,6 +50,7 @@ from repro.kernels import epilogue as _ep
 
 __all__ = [
     "opope_gemm",
+    "opope_gemm_stacked",
     "default_block_shape",
     "validate_block_shape",
     "padding_waste",
@@ -246,49 +252,24 @@ def opope_gemm(
         pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
     ]
     operands = [a_p, b_p]
-    if c is not None:
-        if c.ndim == 1:
-            # [N] bias: streamed as a single (1, bn) row per N tile and
-            # broadcast into the accumulator at preload — O(N) HBM traffic
-            # instead of an O(M*N) materialized C operand.
-            if c.shape != (n,):
-                raise ValueError(f"C preload shape {c.shape} != {(n,)} or {(m, n)}")
-            in_specs.append(pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)))
-            operands.append(_pad2(c[None, :], 1, np_))
-        else:
-            if c.shape != (m, n):
-                raise ValueError(f"C preload shape {c.shape} != {(m, n)}")
-            in_specs.append(pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)))
-            operands.append(_pad2(c, mp, np_))
-        kernel = functools.partial(_gemm_preload_kernel, k_steps=k_steps)
-    else:
-        kernel = functools.partial(_gemm_kernel, k_steps=k_steps)
-
+    if c is not None and c.shape not in ((n,), (m, n)):
+        raise ValueError(f"C preload shape {c.shape} != {(n,)} or {(m, n)}")
+    ep_specs, ep_operands = _streamed_operands(
+        c, epilogue, epilogue_operands, m=m, n=n, mp=mp, np_=np_, bm=bm, bn=bn
+    )
+    in_specs += ep_specs
+    operands += ep_operands
     if epilogue:
-        # One streamed operand per operand-taking step, blocked by kind.
-        # Zero-pad is safe throughout: every built-in op maps 0 -> 0 on the
-        # pad region or the pad is sliced off below before anyone reads it.
-        it = iter(epilogue_operands)
-        for name in epilogue:
-            kind = _ep.op_kind(name)
-            if kind == "none":
-                continue
-            x = next(it)
-            if kind == "scalar":
-                in_specs.append(pl.BlockSpec((1, 1), lambda i, j, kk: (0, 0)))
-                operands.append(x.reshape(1, 1))
-            elif kind == "row":
-                in_specs.append(pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)))
-                operands.append(_pad2(x.reshape(1, n), 1, np_))
-            else:  # full
-                in_specs.append(pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)))
-                operands.append(_pad2(x.reshape(m, n), mp, np_))
         kernel = functools.partial(
             _gemm_epilogue_kernel,
             k_steps=k_steps,
             steps=epilogue,
             has_c=c is not None,
         )
+    elif c is not None:
+        kernel = functools.partial(_gemm_preload_kernel, k_steps=k_steps)
+    else:
+        kernel = functools.partial(_gemm_kernel, k_steps=k_steps)
 
     out = pl.pallas_call(
         kernel,
@@ -304,6 +285,187 @@ def opope_gemm(
         name="opope_gemm",  # the kernel's op name in a profile
     )(*operands)
     return out[:m, :n]
+
+
+def _streamed_operands(
+    c, epilogue, epilogue_operands, *, m, n, mp, np_, bm, bn
+):
+    """BlockSpecs and operands for the C preload and the epilogue steps,
+    padded to ``(mp, np_)``. The index maps ignore trailing scalar-prefetch
+    refs, so both GEMM entries share them.
+
+    C is an [N] bias streamed as one (1, bn) row per N tile and broadcast
+    into the accumulator at preload — O(N) HBM traffic instead of an O(M*N)
+    materialized operand — or a full [M, N] tile stream. Epilogue operands
+    are blocked by kind: (1, 1) scalar, (1, bn) row, (bm, bn) full.
+    Zero-pad is safe throughout: every built-in op maps 0 -> 0 on the pad
+    region or the pad is sliced off before anyone reads it."""
+    specs, operands = [], []
+    row = pl.BlockSpec((1, bn), lambda i, j, kk, *_: (0, j))
+    full = pl.BlockSpec((bm, bn), lambda i, j, kk, *_: (i, j))
+    if c is not None:
+        if c.ndim == 1:
+            specs.append(row)
+            operands.append(_pad2(c[None, :], 1, np_))
+        else:
+            specs.append(full)
+            operands.append(_pad2(c, mp, np_))
+    it = iter(epilogue_operands)
+    for name in epilogue:
+        kind = _ep.op_kind(name)
+        if kind == "none":
+            continue
+        x = next(it)
+        if kind == "scalar":
+            specs.append(pl.BlockSpec((1, 1), lambda i, j, kk, *_: (0, 0)))
+            operands.append(x.reshape(1, 1))
+        elif kind == "row":
+            specs.append(row)
+            operands.append(_pad2(x.reshape(1, n), 1, np_))
+        else:  # full
+            specs.append(full)
+            operands.append(_pad2(x.reshape(m, n), mp, np_))
+    return specs, operands
+
+
+def _gemm_stacked_kernel(
+    layer_ref, *refs, k_steps: int, k_rem: int, steps, has_c: bool
+):
+    """One grid step of :func:`opope_gemm_stacked`: the epilogue kernel's
+    preload, rank update and writeback, with a ragged K edge.
+
+    ``layer_ref`` (the scalar-prefetched layer index) is read by the index
+    maps only. When K is not a multiple of the K tile, the last K step holds
+    ``k_rem`` real columns of the A panel and rows of the B panel; the rest
+    of each panel is whatever the buffer held, and is zeroed before the dot.
+    """
+    del layer_ref
+    a_ref, b_ref = refs[0], refs[1]
+    c_ref = refs[2] if has_c else None
+    ep_refs = refs[3 if has_c else 2:-2]
+    o_ref, acc_ref = refs[-2], refs[-1]
+    k = pl.program_id(2)
+
+    @pl.when(k == 0)
+    def _init():
+        if c_ref is None:
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+        else:
+            acc_ref[...] = jnp.broadcast_to(
+                c_ref[...].astype(jnp.float32), acc_ref.shape
+            )
+
+    def update(a, b):
+        acc_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32)
+
+    if k_rem:
+        @pl.when(k < k_steps - 1)
+        def _inner():
+            update(a_ref[...], b_ref[...])
+
+        @pl.when(k == k_steps - 1)
+        def _edge():
+            a, b = a_ref[...], b_ref[...]
+            a_col = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
+            b_row = jax.lax.broadcasted_iota(jnp.int32, b.shape, 0)
+            update(
+                jnp.where(a_col < k_rem, a, jnp.zeros_like(a)),
+                jnp.where(b_row < k_rem, b, jnp.zeros_like(b)),
+            )
+    else:
+        update(a_ref[...], b_ref[...])
+
+    @pl.when(k == k_steps - 1)
+    def _writeback():
+        acc = _ep.apply_epilogue(
+            acc_ref[...], steps, tuple(r[...] for r in ep_refs)
+        )
+        o_ref[...] = acc.astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=(
+        "block_m",
+        "block_n",
+        "block_k",
+        "out_dtype",
+        "interpret",
+        "epilogue",
+    ),
+)
+def opope_gemm_stacked(
+    a: jax.Array,
+    b: jax.Array,
+    layer: jax.Array,
+    c: Optional[jax.Array] = None,
+    *,
+    block_m: int = 128,
+    block_n: int = 128,
+    block_k: int = 256,
+    out_dtype: Optional[jnp.dtype] = None,
+    interpret: bool = False,
+    epilogue: Tuple[str, ...] = (),
+    epilogue_operands: Tuple[jax.Array, ...] = (),
+) -> jax.Array:
+    """``O = A @ B[layer] (+ C)``; a: [M, K], b: [L, K, N], layer: int32.
+
+    The same dataflow, C preload and epilogue lane as :func:`opope_gemm`,
+    reading layer ``layer`` of a stacked weight in place: the layer index is
+    scalar-prefetched and B's index map selects ``(layer, kk, j)``, so the
+    kernel's DMAs read the stack directly and no ``[K, N]`` slice exists.
+    The weight is never padded: the grid is ``cdiv(N, bn)`` by
+    ``cdiv(K, bk)``, the last N block writes back only its real columns,
+    and the last K step zeroes the panels' rows and columns beyond K. Only
+    A and full-tile operands are padded, to whole M tiles. An out-of-range
+    ``layer`` is clamped, as ``lax.dynamic_index_in_dim`` clamps.
+    """
+    if a.ndim != 2 or b.ndim != 3 or a.shape[1] != b.shape[1]:
+        raise ValueError(f"bad stacked GEMM shapes {a.shape} @ {b.shape}")
+    m, k = a.shape
+    n_layers, _, n = b.shape
+    if c is not None and c.shape not in ((n,), (m, n)):
+        raise ValueError(f"C preload shape {c.shape} != {(n,)} or {(m, n)}")
+    out_dtype = jnp.dtype(out_dtype or a.dtype)
+
+    bm, bn, bk = min(block_m, _rup(m, 8)), min(block_n, _rup(n, 128)), min(
+        block_k, _rup(k, 128)
+    )
+    mp = _rup(m, bm)
+    k_steps = pl.cdiv(k, bk)
+    layer = jnp.clip(jnp.asarray(layer, jnp.int32), 0, n_layers - 1)
+
+    in_specs = [
+        pl.BlockSpec((bm, bk), lambda i, j, kk, l: (i, kk)),
+        pl.BlockSpec((pl.Squeezed(), bk, bn), lambda i, j, kk, l: (l[0], kk, j)),
+    ]
+    ep_specs, ep_operands = _streamed_operands(
+        c, epilogue, epilogue_operands, m=m, n=n, mp=mp, np_=n, bm=bm, bn=bn
+    )
+    kernel = functools.partial(
+        _gemm_stacked_kernel,
+        k_steps=k_steps,
+        k_rem=k % bk,
+        steps=epilogue,
+        has_c=c is not None,
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(mp // bm, pl.cdiv(n, bn), k_steps),
+            in_specs=in_specs + ep_specs,
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk, l: (i, j)),
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((mp, n), out_dtype),
+        compiler_params=compat.tpu_compiler_params(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        ),
+        interpret=interpret,
+        name="opope_gemm",  # the kernel's op name in a profile
+    )(layer.reshape(1), _pad2(a, mp, k), b, *ep_operands)
+    return out[:m]
 
 
 def _rup(x: int, mult: int) -> int:

@@ -79,6 +79,17 @@ backend actually fuses is a per-shape decision: tuning-table verdict first
 pre-epilogue accumulator in the backward pass (one extra GEMM — the fused
 forward never materializes it), backprop through the op pipeline, then run
 the usual two transposed GEMMs on the grad backend.
+
+**Stacked weights read in place** (serving only): ``matmul``/``linear`` take
+``b`` as a :class:`LayerWeight` — one layer of a stacked ``[L, K, N]``
+parameter plus its layer index — so a layer scan need not copy each weight
+out of the stack before the GEMM. Backends registered with
+``reads_stacked=True`` read the layer in place (``pallas``: the kernel's B
+index map selects the layer; ``xla``: a dynamic slice XLA fuses into the
+dot); every other backend (the q8 family, grouped members) is handed the
+slice, exactly what a scan over the stack would hand it. Like the
+pre-quantized A lane this has no custom_vjp; training scans the stack as
+before. ``gemm.calls`` labels each call ``b=stacked`` or ``b=array``.
 """
 
 from __future__ import annotations
@@ -100,6 +111,8 @@ from . import opope_grouped as _gkern
 from . import ref as _ref
 
 __all__ = [
+    "LayerWeight",
+    "materialize",
     "matmul",
     "grouped_matmul",
     "linear",
@@ -130,6 +143,49 @@ __all__ = [
 ]
 
 _DEFAULT_BACKEND = "auto"
+
+
+@functools.partial(
+    jax.tree_util.register_dataclass, data_fields=["stack", "layer"],
+    meta_fields=[],
+)
+@dataclasses.dataclass(frozen=True)
+class LayerWeight:
+    """Layer ``layer`` of a stacked ``[L, ...]`` parameter, not yet sliced.
+
+    A serving layer scan hands these to the model's GEMM sites, so a
+    ``reads_stacked`` backend reads the weight straight from the stack.
+    ``shape``/``dtype`` are the layer's; :meth:`get` is the slice itself
+    (``lax.dynamic_index_in_dim``, which clamps an out-of-range index)."""
+
+    stack: jax.Array
+    layer: jax.Array
+
+    @property
+    def shape(self) -> Tuple[int, ...]:
+        return tuple(self.stack.shape[1:])
+
+    @property
+    def dtype(self):
+        return self.stack.dtype
+
+    def get(self) -> jax.Array:
+        return jax.lax.dynamic_index_in_dim(
+            self.stack, self.layer, keepdims=False
+        )
+
+
+def _is_view(x) -> bool:
+    return isinstance(x, LayerWeight)
+
+
+def materialize(tree):
+    """``tree`` with every :class:`LayerWeight` replaced by its slice; plain
+    arrays pass through unchanged. For parameters that no GEMM reads (norm
+    scales, biases, recurrent-mixer weights)."""
+    return jax.tree.map(
+        lambda x: x.get() if _is_view(x) else x, tree, is_leaf=_is_view
+    )
 
 # --------------------------------------------------------------------------
 # Backend registry
@@ -180,6 +236,9 @@ class _Backend:
     # writeback. Backends without it (the XLA references) get the post-hoc
     # lane in _matmul_impl/_grouped_impl — same numerics, same single cast.
     epilogue_fused: bool = False
+    # Whether fn accepts a LayerWeight as b and reads that layer in place.
+    # matmul() hands the other backends the layer's slice instead.
+    reads_stacked: bool = False
 
 
 _REGISTRY: Dict[str, _Backend] = {}
@@ -200,6 +259,7 @@ def register_backend(
     family: str = "fp",
     tile_fn: Optional[Callable[..., Tuple[int, int, int]]] = None,
     epilogue_fused: bool = False,
+    reads_stacked: bool = False,
 ) -> None:
     """Register (or replace) a matmul backend.
 
@@ -220,7 +280,9 @@ def register_backend(
     heuristic. ``epilogue_fused=True`` declares that ``fn``/``grouped``
     accept ``(a, b, c, out_dtype, ep_steps, ep_ops)`` and fuse the epilogue
     pipeline at the accumulator writeback; backends without it are served by
-    the numerically-identical post-hoc lane.
+    the numerically-identical post-hoc lane. ``reads_stacked=True`` declares
+    that ``fn`` accepts a :class:`LayerWeight` as ``b`` and reads the layer
+    in place; other backends are handed its slice.
     """
     if not callable(fn):
         raise TypeError(f"backend fn for {name!r} is not callable")
@@ -234,6 +296,7 @@ def register_backend(
         name, fn, probe, fallback=tuple(fallback) if fallback else None,
         grad_backend=grad_backend, grouped=grouped, grouped_available=gprobe,
         family=family, tile_fn=tile_fn, epilogue_fused=epilogue_fused,
+        reads_stacked=reads_stacked,
     )
 
 
@@ -854,18 +917,20 @@ def _record_shape(family: str, m: int, k: int, n: int, g: int, dtype) -> None:
 
 def _note_gemm_call(
     shape_family: str, backend: str, m: int, k: int, n: int, groups: int,
-    dtype, b_dtype=None, out_dtype=None,
+    dtype, b_dtype=None, out_dtype=None, b_layout: str = "array",
 ) -> None:
     """Count one GEMM entry-point call into ``gemm.calls``.
 
     Labels carry the resolved backend, its numerics family, the shape
-    family (dense/grouped) and — the introspection the autotuner feeds on —
-    whether the tile and the fusion verdict came from the tuned table or
-    the heuristic/default. When an :class:`repro.obs.attr.capture_gemms`
-    bracket is active, the same facts (plus the actual operand dtypes, for
-    honest byte accounting) are appended as a :class:`GemmRecord` so a timed
-    span owner can attribute its measured step time. Host-side only: inside
-    ``jit`` this runs once at trace time, never per step."""
+    family (dense/grouped), whether B is read in place from a stacked
+    parameter (``b=stacked``) or arrives as an array (``b=array``), and —
+    the introspection the autotuner feeds on — whether the tile and the
+    fusion verdict came from the tuned table or the heuristic/default.
+    When an :class:`repro.obs.attr.capture_gemms` bracket is active, the
+    same facts (plus the actual operand dtypes, for honest byte accounting)
+    are appended as a :class:`GemmRecord` so a timed span owner can
+    attribute its measured step time. Host-side only: inside ``jit`` this
+    runs once at trace time, never per step."""
     if not _obs.enabled():
         return
     b = _REGISTRY.get(backend)
@@ -885,6 +950,7 @@ def _note_gemm_call(
         fusion = "tuned" if verdict is not None else "default"
     _obs.counter(
         "gemm.calls",
+        b=b_layout,
         backend=backend,
         family=b.family if b is not None else "?",
         shape=shape_family,
@@ -963,6 +1029,13 @@ def _pallas_fn(interpret: bool) -> BackendFn:
             a.shape[0], a.shape[1], b.shape[1], jnp.dtype(a.dtype).itemsize,
             family="dense", backend=name,
         )
+        if _is_view(b):
+            return _kern.opope_gemm_stacked(
+                a, b.stack, b.layer, c,
+                block_m=bm, block_n=bn, block_k=bk,
+                out_dtype=out_dtype, interpret=interpret,
+                epilogue=ep_steps, epilogue_operands=ep_ops,
+            )
         return _kern.opope_gemm(
             a, b, c,
             block_m=bm, block_n=bn, block_k=bk,
@@ -996,7 +1069,7 @@ def _pallas_grouped_fn(interpret: bool) -> GroupedFn:
 
 
 def _xla_fn(a, b, c, out_dtype):
-    return _ref.reference_matmul(a, b, c, out_dtype=out_dtype)
+    return _ref.reference_matmul(a, materialize(b), c, out_dtype=out_dtype)
 
 
 def _xla_grouped_fn(a, b, c, out_dtype):
@@ -1009,14 +1082,18 @@ register_backend(
     grouped_available=_pallas_grouped_compiles,
     tile_fn=_kern.default_block_shape,
     epilogue_fused=True,
+    reads_stacked=True,
 )
 register_backend(
     "pallas_interpret", _pallas_fn(interpret=True),
     grouped=_pallas_grouped_fn(interpret=True),
     tile_fn=_kern.default_block_shape,
     epilogue_fused=True,
+    reads_stacked=True,
 )
-register_backend("xla", _xla_fn, grouped=_xla_grouped_fn)
+register_backend(
+    "xla", _xla_fn, grouped=_xla_grouped_fn, reads_stacked=True
+)
 
 
 @functools.lru_cache(maxsize=None)
@@ -1233,6 +1310,10 @@ def matmul(
     ``requant_int8`` epilogue upstream) on a q8-family backend: the backend
     skips its A-quantization pass and consumes the int8 values directly.
     This is a serving-only lane (no custom_vjp).
+
+    ``b`` may be a :class:`LayerWeight` (one layer of a stacked parameter),
+    and ``c`` too: a ``reads_stacked`` backend reads ``b`` in place, any
+    other gets its slice. That is a serving-only lane as well.
     """
     pre_q = hasattr(a, "q") and hasattr(a, "scale")
     arr = a.q if pre_q else a
@@ -1240,6 +1321,10 @@ def matmul(
     # input is not a meaningful default for the dequantized result).
     out_dtype = jnp.dtype(out_dtype or (jnp.float32 if pre_q else arr.dtype))
     backend = resolve_backend(backend)
+    c = materialize(c)
+    in_place = _is_view(b) and _REGISTRY[backend].reads_stacked
+    if _is_view(b) and not in_place:
+        b = b.get()
     batch_shape = arr.shape[:-1]
     m = 1
     for d in batch_shape:
@@ -1248,6 +1333,7 @@ def matmul(
     _note_gemm_call(
         "dense", backend, m, arr.shape[-1], b.shape[-1], 0, arr.dtype,
         b_dtype=b.dtype, out_dtype=out_dtype,
+        b_layout="stacked" if in_place else "array",
     )
     n = b.shape[-1]
     steps, raw_ops = _epi.normalize_epilogue(epilogue)
@@ -1276,6 +1362,13 @@ def matmul(
         return out.reshape(*batch_shape, n)
 
     a2 = arr.reshape(m, arr.shape[-1])
+    if in_place:
+        # No custom_vjp: nothing differentiates a serving step, and training
+        # scans the stack, so its GEMMs never see a LayerWeight.
+        ep_ops = _epi.canonicalize_operands(steps, raw_ops, n=n, m=m)
+        c2 = c.reshape(m, n) if c is not None and c.ndim > 1 else c
+        out = _matmul_impl(a2, b, c2, backend, out_dtype, steps, ep_ops)
+        return out.reshape(*batch_shape, n)
     if steps:
         ep_ops = _epi.canonicalize_operands(steps, raw_ops, n=n, m=m)
         out = _matmul_ep(a2, b, ep_ops, backend, out_dtype, steps)
@@ -1537,6 +1630,8 @@ def grouped_matmul(
     ``[G, N]`` row, or full ``[G, M, N]``. A ``c`` alongside an epilogue is
     folded in as the pipeline's first step.
     """
+    # Grouped members read no stacked weight in place: hand them the slice.
+    b, c = materialize(b), materialize(c)
     if a.ndim != 3 or b.ndim != 3:
         raise ValueError(
             f"grouped_matmul wants a [G, M, K] @ [G, K, N]; got {a.shape} @ {b.shape}"

@@ -16,6 +16,13 @@ Modes:
 
 Caches are per-period-position stacked pytrees (KVCache / MambaState /
 MLSTMState / SLSTMState), scanned alongside the parameters.
+
+The serving modes (``prefill``, ``decode``, ``chunk``) scan the layer index
+instead of the parameters: each block gets ``ops.LayerWeight`` views of the
+stacked weights, which its GEMMs read in place, and every other parameter is
+sliced (``ops.materialize``). ``train`` scans the stacked
+parameters as ``xs``: under ``jax.grad`` a closed-over stack would turn each
+layer's weight gradient into a scatter-add into a full ``[L, ...]`` zero.
 """
 
 from __future__ import annotations
@@ -27,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, BlockDef
+from repro.kernels import ops
 from . import attention as attn_mod
 from . import mamba as mamba_mod
 from . import xlstm as xlstm_mod
@@ -190,12 +198,16 @@ def _block_apply(
     backend=None,
     chunk=False,
 ):
-    """One layer. Returns (x, new_cache, aux_loss)."""
+    """One layer. Returns (x, new_cache, aux_loss).
+
+    ``p``'s leaves are arrays or, in the serving modes, ``ops.LayerWeight``
+    views: attention, MLP and MoE pass them to their GEMMs, and everything
+    else takes the slice (``ops.materialize``, the identity on arrays)."""
     from .layers import role_backend
 
     aux = jnp.zeros((), jnp.float32)
     new_cache = cache
-    h = _norm(cfg, p["norm_mixer"], x)
+    h = _norm(cfg, ops.materialize(p["norm_mixer"]), x)
     mixer_out = None
     stream = x  # the residual stream after the mixer's skip connection
     # attention / mlp / moe resolve their own precision-policy roles inside;
@@ -205,6 +217,11 @@ def _block_apply(
         # Recurrent state can't resume mid-prompt from a cache scatter; the
         # engine gates chunked prefill to attention-only patterns.
         raise NotImplementedError("chunked prefill requires attention mixers")
+    # Recurrent mixers take their weights as slices, as in training.
+    rec_p = (
+        ops.materialize(p[bd.mixer])
+        if bd.mixer in ("mamba", "mlstm", "slstm") else None
+    )
     if bd.mixer in ("attn", "attn_local"):
         # The mixer's residual add rides the output projection's epilogue:
         # attention returns x + attn(h) in one writeback.
@@ -231,11 +248,11 @@ def _block_apply(
     elif bd.mixer == "mamba":
         if cache is not None and x.shape[1] == 1:
             mixer_out, new_cache = mamba_mod.mamba_decode_step(
-                p["mamba"], h, cache, backend=mixer_be
+                rec_p, h, cache, backend=mixer_be
             )
         else:
             mixer_out, state = mamba_mod.mamba_apply(
-                p["mamba"], h, chunk=cfg.scan_chunk, backend=mixer_be,
+                rec_p, h, chunk=cfg.scan_chunk, backend=mixer_be,
                 return_state=True,
             )
             if cache is not None:
@@ -243,11 +260,11 @@ def _block_apply(
     elif bd.mixer == "mlstm":
         if cache is not None and x.shape[1] == 1:
             mixer_out, new_cache = xlstm_mod.mlstm_decode_step(
-                p["mlstm"], h, cache, n_heads=cfg.n_heads, backend=mixer_be
+                rec_p, h, cache, n_heads=cfg.n_heads, backend=mixer_be
             )
         else:
             mixer_out, state = xlstm_mod.mlstm_apply(
-                p["mlstm"], h, n_heads=cfg.n_heads, chunk=cfg.scan_chunk,
+                rec_p, h, n_heads=cfg.n_heads, chunk=cfg.scan_chunk,
                 backend=mixer_be, return_state=True,
             )
             if cache is not None:
@@ -255,11 +272,11 @@ def _block_apply(
     elif bd.mixer == "slstm":
         if cache is not None and x.shape[1] == 1:
             mixer_out, new_cache = xlstm_mod.slstm_decode_step(
-                p["slstm"], h, cache, n_heads=cfg.n_heads, backend=mixer_be
+                rec_p, h, cache, n_heads=cfg.n_heads, backend=mixer_be
             )
         else:
             mixer_out, state = xlstm_mod.slstm_apply(
-                p["slstm"], h, n_heads=cfg.n_heads, backend=mixer_be,
+                rec_p, h, n_heads=cfg.n_heads, backend=mixer_be,
                 return_state=True,
             )
             if cache is not None:
@@ -283,13 +300,13 @@ def _block_apply(
     if bd.ffn == "mlp":
         # Pre-norm FFN with its skip connection fused into the down GEMM.
         stream = mlp_apply(
-            p["mlp"], _norm(cfg, p["norm_ffn"], stream), backend=backend,
-            residual=stream,
+            p["mlp"], _norm(cfg, ops.materialize(p["norm_ffn"]), stream),
+            backend=backend, residual=stream,
         )
     elif bd.ffn == "moe":
         y, aux = moe_apply(
             p["moe"],
-            _norm(cfg, p["norm_ffn"], stream),
+            _norm(cfg, ops.materialize(p["norm_ffn"]), stream),
             n_experts=cfg.moe.n_experts,
             top_k=cfg.moe.top_k,
             capacity_factor=cfg.moe.capacity_factor,
@@ -304,6 +321,10 @@ def _block_apply(
         # block-residual add stays a plain op.
         stream = stream + y
     return stream, new_cache, aux
+
+
+# The modes that scan the layer index and read weights in place (module doc).
+_IN_PLACE_MODES = frozenset({"prefill", "decode", "chunk"})
 
 
 def lm_forward(
@@ -332,11 +353,19 @@ def lm_forward(
     n_pos = len(cfg.pattern)
     have_caches = caches is not None
     chunk = mode == "chunk"  # chunked prefill: scatter-append at `positions`
+    in_place = mode in _IN_PLACE_MODES
 
     def period_body(carry, xs):
         x, aux = carry
-        block_params = xs[:n_pos]
-        block_caches = xs[n_pos:] if have_caches else (None,) * n_pos
+        if in_place:
+            layer, xs = xs[0], xs[1:]
+            block_params = tuple(
+                jax.tree.map(lambda w: ops.LayerWeight(w, layer), blk)
+                for blk in params["blocks"]
+            )
+        else:
+            block_params, xs = xs[:n_pos], xs[n_pos:]
+        block_caches = xs if have_caches else (None,) * n_pos
         new_caches = []
         for pos, bd in enumerate(cfg.pattern):
             cache_in = block_caches[pos]
@@ -370,7 +399,10 @@ def lm_forward(
         else:
             body = jax.checkpoint(period_body)
 
-    xs = tuple(params["blocks"])
+    if in_place:
+        xs = (jnp.arange(cfg.n_periods, dtype=jnp.int32),)
+    else:
+        xs = tuple(params["blocks"])
     if have_caches:
         xs = xs + tuple(
             c if c is not None else _none_stack(cfg.n_periods) for c in caches

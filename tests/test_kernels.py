@@ -81,6 +81,53 @@ def test_gemm_bias_row_preload(m, k, n):
     assert _err(got, want) < 1e-4
 
 
+# The stacked-weight entry at every layer of a small [L, K, N] stack, with
+# K and N ragged at 128-wide tiles (the interpreter fills the out-of-range
+# parts of each edge panel with NaN, so an unmasked edge shows), under each
+# C preload and epilogue the models use: (C kind, epilogue steps).
+STACKED = {
+    "plain": (None, ()),
+    "bias_preload": ("row", ()),
+    "c_preload": ("full", ()),
+    "silu_mul": (None, ("silu", "mul")),
+    "bias_silu_mul": ("row", ("silu", "mul")),
+    "residual": (None, ("residual",)),
+    "bias_epilogue": (None, ("bias",)),
+}
+
+
+@pytest.mark.parametrize("dt", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(STACKED))
+def test_stacked_gemm_matches_oracle(case, dt):
+    from repro.kernels.opope_gemm import opope_gemm_stacked
+
+    n_layers, m, k, n = 3, 5, 200, 300
+    c_kind, steps = STACKED[case]
+    a, b = _rand((m, k), dt), _rand((n_layers, k, n), dt)
+    c = {None: None, "row": _rand((n,), dt), "full": _rand((m, n), dt)}[c_kind]
+    ep_ops = {"mul": _rand((m, n), dt), "residual": _rand((m, n), dt),
+              "bias": _rand((1, n), dt)}
+    operands = tuple(ep_ops[s] for s in steps if s in ep_ops)
+    tol = 1e-4 if dt == jnp.float32 else 2e-1
+    for layer in range(n_layers):
+        got = opope_gemm_stacked(
+            a, b, jnp.int32(layer), c, block_m=128, block_n=128,
+            block_k=128, interpret=True, epilogue=steps,
+            epilogue_operands=operands,
+        )
+        acc = reference_matmul(a, b[layer], c, out_dtype=jnp.float32)
+        for s in steps:
+            acc = {
+                "silu": lambda x: x * jax.nn.sigmoid(x),
+                "mul": lambda x: x * ep_ops["mul"].astype(jnp.float32),
+                "residual": lambda x: x + ep_ops["residual"].astype(jnp.float32),
+                "bias": lambda x: x + ep_ops["bias"].astype(jnp.float32),
+            }[s](acc)
+        want = acc.astype(dt)
+        assert got.shape == (m, n) and got.dtype == want.dtype
+        assert _err(got, want) < tol, (case, layer)
+
+
 def test_linear_bias_grad_is_column_sum():
     ops.set_default_backend("pallas_interpret")
     try:

@@ -244,7 +244,10 @@ class TestKernelTelemetry:
         b = jnp.ones((16, 8), jnp.float32)
         ops.matmul(a, b, backend="xla")
         snap = obs.snapshot()
-        key = "backend=xla,family=fp,fusion=none,shape=dense,tile=heuristic"
+        key = (
+            "b=array,backend=xla,family=fp,fusion=none,shape=dense,"
+            "tile=heuristic"
+        )
         assert snap["counters"]["gemm.calls"][key] == 1.0
 
     def test_grouped_gemm_call_counter(self):

@@ -13,6 +13,7 @@ imports this file. The persistent compilation cache is off around these
 compiles (an entry compiled for a described chip cannot be read back here).
 """
 
+import dataclasses
 import functools
 import os
 
@@ -181,8 +182,11 @@ def test_chunked_scan_compiles(one_chip):
 @pytest.mark.parametrize("step", ["decode", "prefill"])
 def test_chatglm3_step_compiles_and_fits(one_chip, compiled_pallas, step):
     """The whole 28-layer chatglm3-6b serving step, as the continuous engine
-    runs it (4 slots; a 4 x 512 prefill bucket), on the compiled kernels:
-    parameters plus temporaries fit one v5e's 16 GiB."""
+    runs it (32 slots of 2048, the chat cell's pool; a 4 x 512 prefill
+    bucket), on the compiled kernels: parameters plus temporaries fit one
+    v5e's 16 GiB. The decode step's temporaries stay below the 2.24 GB they
+    took while each layer's weights were copied out of the stack before its
+    GEMMs (compile for a described v5e)."""
     from repro.models import api
 
     assert ops.resolve_backend("auto") == "pallas"
@@ -195,13 +199,13 @@ def test_chatglm3_step_compiles_and_fits(one_chip, compiled_pallas, step):
     i32 = jnp.int32
     if step == "decode":
         caches = on_chip(jax.eval_shape(
-            lambda: api.init_state(CFG, 4, 2048, jnp.bfloat16)))
+            lambda: api.init_state(CFG, 32, 2048, jnp.bfloat16)))
         fn = jax.jit(
             lambda p, c, t, pos: api.decode_at(CFG, p, t, c, pos),
             donate_argnums=(1,),
         )
-        specs = (params, caches, _spec((4, 1), i32, one_chip),
-                 _spec((4,), i32, one_chip))
+        specs = (params, caches, _spec((32, 1), i32, one_chip),
+                 _spec((32,), i32, one_chip))
     else:
         fn = jax.jit(lambda p, t, n: api.prefill_bucketed(CFG, p, t, n))
         specs = (params, _spec((4, 512), i32, one_chip),
@@ -210,8 +214,13 @@ def test_chatglm3_step_compiles_and_fits(one_chip, compiled_pallas, step):
     _assert_kernel(compiled, at_least=4)
     ma = compiled.memory_analysis()
     need = ma.argument_size_in_bytes + ma.temp_size_in_bytes
-    assert 12 * GiB > ma.argument_size_in_bytes > 11 * GiB  # full width, bf16
     assert need < 16 * GiB, f"{need / GiB:.2f} GiB > 16 GiB"
+    if step == "decode":
+        # weights (11.6 GiB, full width, bf16) and the 1.75 GiB pool
+        assert 14 * GiB > ma.argument_size_in_bytes > 13 * GiB
+        assert ma.temp_size_in_bytes < 2.24e9, ma.temp_size_in_bytes
+    else:
+        assert 12 * GiB > ma.argument_size_in_bytes > 11 * GiB
 
 
 def test_engine_program_and_kernel_names(one_chip, compiled_pallas):
@@ -264,3 +273,80 @@ def test_engine_program_and_kernel_names(one_chip, compiled_pallas):
         kernels = custom_call.findall(text)
         if kind is not None:
             assert kernels and all(k.startswith(trace.GEMM_KERNEL) for k in kernels)
+
+
+# The configurations as the benchmark serves them (chatglm3-6b with its
+# published Q/K/V bias, stablelm-12b with its published rotary fraction);
+# the test cuts each to two layers.
+ENGINE_CFGS = {
+    "chatglm3-6b": lambda: dataclasses.replace(CFG, qkv_bias=True),
+    "stablelm-12b.pp4": lambda: dataclasses.replace(
+        get_config("stablelm-12b"), rope_frac=0.25),
+}
+
+
+def _weight_shapes(params) -> set:
+    """Every layer GEMM weight's ``[K, N]``, and each padded to any mix of
+    128-, 256- and 512-wide tiles."""
+    def rup(x, t):
+        return -(-x // t) * t
+
+    out = set()
+    for leaf in jax.tree.leaves(params["blocks"]):
+        if leaf.ndim != 3:
+            continue
+        k, n = leaf.shape[1:]
+        out |= {(rup(k, tk), rup(n, tn))
+                for tk in (1, 128, 256, 512) for tn in (1, 128, 256, 512)}
+    return out
+
+
+@pytest.mark.parametrize("arch", sorted(ENGINE_CFGS))
+def test_engine_steps_read_weights_in_place(one_chip, compiled_pallas, arch):
+    """The engine's decode and prefill programs feed each layer's weights
+    to the GEMM kernels straight from the stacked parameters: no
+    instruction outside a kernel produces an array of a layer weight's
+    shape, its own or padded to the kernel's tiles (a slice of the stack or
+    a pad before the kernel would), and every Mosaic kernel is
+    ``opope_gemm``."""
+    import re
+
+    from repro.models import api
+    from repro.serve import ContinuousEngine
+    from repro.serve.cache import init_slot_caches
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _spec(x.shape, x.dtype, one_chip), tree)
+
+    cfg = dataclasses.replace(ENGINE_CFGS[arch](), n_layers=2)
+    params = on_chip(jax.eval_shape(
+        functools.partial(api.init_params, cfg), jax.random.key(0)))
+    eng = ContinuousEngine(cfg=cfg, params=params, n_slots=4, max_len=256)
+    pool = on_chip(jax.eval_shape(
+        lambda: init_slot_caches(cfg, 4, 256, eng.cache_dtype)))
+    i32 = jnp.int32
+    programs = {
+        "decode": eng._decode.lower(
+            params, pool, _spec((4, 1), i32, one_chip), _spec((4,), i32, one_chip),
+            _spec((4,), jnp.bool_, one_chip),
+            on_chip(jax.eval_shape(lambda: jax.random.key(0)))),
+        "prefill": eng._prefill.lower(
+            params, _spec((2, 128), i32, one_chip), _spec((2,), i32, one_chip)),
+    }
+    weights = _weight_shapes(params)
+    instr = re.compile(
+        r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* (\S+?)\((.*)$", re.M)
+    for kind, lowered in programs.items():
+        text = lowered.compile().as_text()
+        copies, kernels = [], []
+        for name, dims, op, rest in instr.findall(text):
+            if 'custom_call_target="tpu_custom_call"' in rest:
+                kernels.append(name)
+                continue
+            shape = tuple(int(d) for d in dims.split(",") if d)
+            while shape and shape[0] == 1:
+                shape = shape[1:]  # a one-layer slice keeps its layer axis
+            if shape in weights:
+                copies.append(f"{name} = {op}{list(shape)}")
+        assert not copies, f"{kind}: weight-sized copies {copies}"
+        assert kernels and all(k.startswith("opope_gemm") for k in kernels), kernels
