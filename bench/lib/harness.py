@@ -32,7 +32,6 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import gc
-import importlib.util
 import json
 import os
 import shutil
@@ -43,17 +42,12 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from . import costs, spec, traffic
+from . import spec, traffic
 from .trace import MARK_END, MARK_JOIN, MARK_START
 
 COMPILE_EVENTS = (
     "/jax/core/compile/backend_compile_duration",
     "/jax/compilation_cache/cache_retrieval_time_sec",
-)
-SERVED_KEYS = (
-    "n_layers", "d_model", "n_heads", "n_kv", "head_dim", "d_ff", "vocab",
-    "norm", "rope_frac", "rope_theta", "parallel_block", "tie_embeddings",
-    "qkv_bias", "param_dtype",
 )
 
 
@@ -180,6 +174,7 @@ class RunData:
     """What the metric readers read."""
 
     cfg: Dict
+    family: object  # the configuration's module under bench/reference/
     peaks: object
     n_slots: int
     setup_s: float
@@ -213,23 +208,22 @@ class RunData:
 
 def served_config(cell: spec.Cell):
     """The program's ArchConfig for the cell, checked key by key against
-    the configuration file."""
+    the configuration file by the configuration's family module."""
     from repro.configs import get_config
 
     c = cell.config
     cfg = dataclasses.replace(get_config(c["arch"]), **c.get("changes", {}))
-    served = {k: getattr(cfg, k) for k in SERVED_KEYS if k != "head_dim"}
-    served["head_dim"] = cfg.head_dim_
-    for k in SERVED_KEYS:
+    for k, v in cell.family.served(cfg).items():
         if k not in c["config"]:
             raise ValueError(f"{c['name']}: configuration file lacks {k!r}")
-        if c["config"][k] != served[k]:
+        if c["config"][k] != v:
             raise ValueError(
-                f"{c['name']}: the program would run {k}={served[k]!r}, "
+                f"{c['name']}: the program would run {k}={v!r}, "
                 f"the file states {c['config'][k]!r}"
             )
-    if cfg.moe is not None or cfg.window or cfg.attn_softcap or cfg.final_softcap:
-        raise ValueError(f"{c['name']}: mechanisms the dense reference lacks")
+    why = cell.family.refuses(cfg)
+    if why:
+        raise ValueError(f"{c['name']}: {why}")
     return cfg
 
 
@@ -346,12 +340,13 @@ def run_once(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     if not cell.traffic.get("stream", True):
         raise ValueError("the harness streams every token through on_token; a mix that "
                          "does not stream needs another client")
+    fam = cell.family
     cfg = served_config(cell)
-    log(f"[setup] {cell.config['name']}: {costs.weight_bytes(cfgd) / 1e9:.3f} GB of weights, "
-        f"KV pool {costs.kv_bytes_per_token(cfgd) * d['slots'] * d['max_len'] / 1e9:.3f} GB "
+    log(f"[setup] {cell.config['name']}: {fam.weight_bytes(cfgd) / 1e9:.3f} GB of weights, "
+        f"KV pool {fam.kv_bytes_per_token(cfgd) * d['slots'] * d['max_len'] / 1e9:.3f} GB "
         f"({d['slots']} slots of {d['max_len']})")
     t = time.perf_counter()
-    params = weights.init_params(cfgd, seed)
+    params = weights.init_params(fam.layout(cfgd), seed)
     jax.block_until_ready(params)
     check_layout(cfg, params)
     log(f"[setup] weights from seed {seed}: {time.perf_counter() - t:.3f}s")
@@ -415,8 +410,9 @@ def run_once(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     if backends != [backend] or degr:
         raise InvalidRun(f"serving GEMMs ran on {backends} (degradations {degr}), not {backend!r}")
 
-    run = RunData(cfg=cfgd, peaks=peaks, n_slots=d["slots"], setup_s=client.t_open - t_start,
-                  t_open=client.t_open, t_close=client.t_close, times=client.times, plen=plen)
+    run = RunData(cfg=cfgd, family=fam, peaks=peaks, n_slots=d["slots"],
+                  setup_s=client.t_open - t_start, t_open=client.t_open,
+                  t_close=client.t_close, times=client.times, plen=plen)
     joins = [ts[0] for ts in client.times.values() if client.t_open <= ts[0] < client.t_close]
     attempted = sum(1 for ts in client.times.values()
                     if ts[0] < client.t_close and ts[-1] >= client.t_open)
@@ -481,14 +477,6 @@ def sample(seed: int, outputs: Dict[int, List[int]], want: int) -> List[int]:
     return pick
 
 
-def load_reference(cell: spec.Cell):
-    path = os.path.join(cell.bench_dir, "reference", cell.config["reference"] + ".py")
-    s = importlib.util.spec_from_file_location("bench_reference_" + cell.config["reference"], path)
-    mod = importlib.util.module_from_spec(s)
-    s.loader.exec_module(mod)
-    return mod
-
-
 def widest_gap(logits: List[np.ndarray], tokens: List[np.ndarray]) -> float:
     """Widest gap, over every position, between the reference's best logit
     and its logit of the token given there."""
@@ -507,18 +495,18 @@ def output_check(cell: spec.Cell, seed: int, prompts, outputs, control: bool) ->
     if not pick:
         log("check no finished request to compare")
         return {"correct": False, "checks": {"max_logit_gap": {"value": None, "limit": limit}}}
-    ref = load_reference(cell)
+    fam = cell.family
     seqs = [np.concatenate([prompts[r], np.asarray(outputs[r][:-1], np.int32)]) for r in pick]
     starts = [len(prompts[r]) - 1 for r in pick]
     t = time.perf_counter()
-    logits = ref.logits_at(cell.config["config"], seed, seqs, starts)
+    logits = fam.logits_at(cell.config["config"], seed, seqs, starts)
     tokens = [np.asarray(outputs[r]) for r in pick]
     log(f"[check] reference over {len(pick)} requests, {sum(map(len, tokens))} served tokens, "
         f"{sum(len(s) for s in seqs)} positions: {time.perf_counter() - t:.3f}s")
     if control:
         log(f"[check] control: the served tokens' own gap is {widest_gap(logits, tokens)!r}; "
             f"the float8 reference's first tokens take their place")
-        ctl = ref.logits_at(cell.config["config"], seed, seqs, starts, quant="fp8")
+        ctl = fam.logits_at(cell.config["config"], seed, seqs, starts, quant="fp8")
         tokens = [c.argmax(-1) for c in ctl]
     gap = widest_gap(logits, tokens)
     correct = bool(np.isfinite(gap) and gap <= limit)

@@ -5,14 +5,17 @@ configuration and its traffic mix. Everything else is found by name:
 ``bench/cells/<cell>.json`` (the deployment: slots, cache length, prefill
 settings, backlog, and the limit of the output check),
 ``bench/traffic/<mix>.json`` (the mix's lengths), the configuration's
-``file`` and ``bench/metrics/<metric>.py`` (one reader per metric). A new
-cell, mix, configuration or metric is files of its own plus entries in
-``BENCHMARK.json``.
+``file``, the family module ``bench/reference/<family>.py`` that the
+configuration names by ``"reference"`` (its shapes, parameter tree, GEMM
+costs and float32 reference) and ``bench/metrics/<metric>.py`` (one reader
+per metric). A new cell, mix, configuration, family or metric is files of
+its own plus entries in ``BENCHMARK.json``.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import os
@@ -31,11 +34,21 @@ class Cell:
     bench_dir: str
 
     def reader(self, metric: str):
-        path = os.path.join(self.bench_dir, "metrics", metric + ".py")
-        spec = importlib.util.spec_from_file_location("bench_metric_" + metric.replace(".", "_"), path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return _module(self.bench_dir, "metrics", metric).read
+
+    @functools.cached_property
+    def family(self):
+        """The configuration's family module, loaded on first use (it
+        imports JAX, which reads its settings from the environment then)."""
+        return _module(self.bench_dir, "reference", self.config["reference"])
+
+
+def _module(bench_dir: str, kind: str, name: str):
+    path = os.path.join(bench_dir, kind, name + ".py")
+    spec = importlib.util.spec_from_file_location(f"bench_{kind}_" + name.replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def _json(path: str) -> Dict:

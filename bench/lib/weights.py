@@ -3,12 +3,15 @@ the type they are served in, and the same weights again one layer at a
 time for the reference.
 
 Every leaf has a key of its own (the seed's key folded with the leaf's
-index), and every layer of a stacked leaf the leaf's key folded with the
-layer's index. So the reference can regenerate layer ``l`` alone, and gets
-the same values that the program was given.
+index in the layout), and every layer of a stacked leaf the leaf's key
+folded with the layer's index. So the reference can regenerate layer ``l``
+alone, and gets the same values that the program was given.
 
-The layout (paths, shapes, dtypes) is the served program's parameter tree;
-the harness checks it against the program's own before a run.
+The layout (paths, shapes, dtypes) is the served program's parameter tree,
+as the configuration's family module (``bench/reference/<family>.py``)
+lists it; the harness checks it against the program's own before a run.
+An integer in a path is a position in a tuple (``("blocks", 0, ...)`` is
+the first group of stacked layers).
 """
 
 from __future__ import annotations
@@ -26,39 +29,7 @@ class Leaf(NamedTuple):
     dtype: str
     kind: str  # dense | embed | scale | bias
     fan_in: int
-    stacked: bool
-
-
-def layout(cfg: Dict) -> List[Leaf]:
-    d, hd, ff, v = cfg["d_model"], cfg["head_dim"], cfg["d_ff"], cfg["vocab"]
-    q, kv = cfg["n_heads"] * hd, cfg["n_kv"] * hd
-    w = cfg["param_dtype"]
-    ln = cfg["norm"] == "layernorm"
-    out = [Leaf(("embed", "table"), (v, d), w, "embed", d, False)]
-    if not cfg["tie_embeddings"]:
-        out.append(Leaf(("lm_head",), (v, d), w, "dense", d, False))
-    out.append(Leaf(("final_norm", "scale"), (d,), "float32", "scale", d, False))
-    if ln:
-        out.append(Leaf(("final_norm", "bias"), (d,), "float32", "bias", d, False))
-    blk = ("blocks", 0)
-    for norm in ("norm_mixer", "norm_ffn"):
-        out.append(Leaf(blk + (norm, "scale"), (d,), "float32", "scale", d, True))
-        if ln:
-            out.append(Leaf(blk + (norm, "bias"), (d,), "float32", "bias", d, True))
-    out += [
-        Leaf(blk + ("attn", "wq", "w"), (d, q), w, "dense", d, True),
-        Leaf(blk + ("attn", "wk", "w"), (d, kv), w, "dense", d, True),
-        Leaf(blk + ("attn", "wv", "w"), (d, kv), w, "dense", d, True),
-        Leaf(blk + ("attn", "wo", "w"), (q, d), w, "dense", q, True),
-        Leaf(blk + ("mlp", "w_up"), (d, ff), w, "dense", d, True),
-        Leaf(blk + ("mlp", "w_gate"), (d, ff), w, "dense", d, True),
-        Leaf(blk + ("mlp", "w_down"), (ff, d), w, "dense", ff, True),
-    ]
-    if cfg["qkv_bias"]:
-        # last, so that the keys of every other leaf stay as they are
-        out += [Leaf(blk + ("attn", p, "b"), (n,), w, "bias", d, True)
-                for p, n in (("wq", q), ("wk", kv), ("wv", kv))]
-    return out
+    stacked: int  # layers in the leaf's stack; 0 for a leaf outside the layers
 
 
 def root_key(seed: int) -> jax.Array:
@@ -88,34 +59,37 @@ def _leaf_key(root, i: int):
 def _set(tree: Dict, path: Tuple, value) -> None:
     node = tree
     for p in path[:-1]:
-        if p == 0:
-            continue
         node = node.setdefault(p, {})
     node[path[-1]] = value
 
 
-def _finish(tree: Dict) -> Dict:
-    if "blocks" in tree:
-        tree["blocks"] = (tree["blocks"],)
-    return tree
+def _tuples(node):
+    """Dicts keyed 0..n-1 become tuples, bottom up."""
+    if not isinstance(node, dict):
+        return node
+    out = {k: _tuples(v) for k, v in node.items()}
+    if out and all(isinstance(k, int) for k in out):
+        return tuple(out[i] for i in range(len(out)))
+    return out
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def _init(leaves: Tuple[Leaf, ...], n_layers: int, root):
+@functools.partial(jax.jit, static_argnums=(0,))
+def _init(leaves: Tuple[Leaf, ...], root):
     tree: Dict = {}
     for i, leaf in enumerate(leaves):
         key = _leaf_key(root, i)
         if leaf.stacked:
-            x = jax.vmap(lambda l: _draw(key, leaf, l))(jnp.arange(n_layers))
+            x = jax.vmap(lambda l: _draw(key, leaf, l))(jnp.arange(leaf.stacked))
         else:
             x = _draw(key, leaf, 0)
         _set(tree, leaf.path, x)
-    return _finish(tree)
+    return _tuples(tree)
 
 
-def init_params(cfg: Dict, seed: int):
-    """The whole served parameter tree, on the default device."""
-    return _init(tuple(layout(cfg)), cfg["n_layers"], root_key(seed))
+def init_params(leaves: List[Leaf], seed: int):
+    """The whole served parameter tree of ``leaves`` (a family's
+    ``layout``), on the default device."""
+    return _init(tuple(leaves), root_key(seed))
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
@@ -123,10 +97,11 @@ def _layer(leaves: Tuple[Tuple[int, Leaf], ...], root, layer):
     return {leaf.path[2:]: _draw(_leaf_key(root, i), leaf, layer) for i, leaf in leaves}
 
 
-def layer_weights(cfg: Dict, seed: int, layer: int) -> Dict[Tuple, jax.Array]:
-    """Layer ``layer``'s leaves, keyed by their path below the block."""
-    leaves = tuple((i, l) for i, l in enumerate(layout(cfg)) if l.stacked)
-    return _layer(leaves, root_key(seed), jnp.int32(layer))
+def layer_weights(leaves: List[Leaf], seed: int, layer: int, group: int = 0) -> Dict[Tuple, jax.Array]:
+    """Layer ``layer`` of the stacked group ``("blocks", group)``, keyed by
+    each leaf's path below the group."""
+    sel = tuple((i, l) for i, l in enumerate(leaves) if l.stacked and l.path[:2] == ("blocks", group))
+    return _layer(sel, root_key(seed), jnp.int32(layer))
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
@@ -134,7 +109,7 @@ def _top(leaves: Tuple[Tuple[int, Leaf], ...], root):
     return {leaf.path: _draw(_leaf_key(root, i), leaf, 0) for i, leaf in leaves}
 
 
-def top_weights(cfg: Dict, seed: int) -> Dict[Tuple, jax.Array]:
+def top_weights(leaves: List[Leaf], seed: int) -> Dict[Tuple, jax.Array]:
     """The leaves outside the layers: embedding, LM head, final norm."""
-    leaves = tuple((i, l) for i, l in enumerate(layout(cfg)) if not l.stacked)
-    return _top(leaves, root_key(seed))
+    sel = tuple((i, l) for i, l in enumerate(leaves) if not l.stacked)
+    return _top(sel, root_key(seed))

@@ -1,9 +1,8 @@
 """Whole step: the model operations of every prompt prefilled and every
 token streamed in the traced window (matmuls, attention at the live
 context, the LM head where a token gets logits) over the window's seconds,
-as a share of the chip's bf16 peak."""
-
-from lib import costs
+as a share of the chip's bf16 peak. The operations are the family's
+``prompt_flops`` and ``token_flops``."""
 
 
 def read(run):
@@ -14,7 +13,7 @@ def read(run):
         for j, t in enumerate(times):
             if t0 <= t < t1:
                 flops += (
-                    costs.prompt_flops(run.cfg, plen) if j == 0
-                    else costs.token_flops(run.cfg, plen + j, lm_head=True)
+                    run.family.prompt_flops(run.cfg, plen) if j == 0
+                    else run.family.token_flops(run.cfg, plen + j, lm_head=True)
                 )
     return flops / (t1 - t0) / run.peaks.bf16_flops * 100.0 if flops else None

@@ -23,6 +23,13 @@ the served arrangement, so that the comparison judges the arithmetic:
 ``quant="fp8"`` computes every matmul on operands rounded to float8 e4m3,
 scaled per row or column along the contraction: the precision control,
 one step below the configuration's bfloat16.
+
+This module is the dense family's one place for its shapes. Besides the
+reference (``logits_at``) it gives the harness what the program would
+serve (``served``, ``refuses``), the parameter tree (``layout``), the layer
+GEMMs that run through the ``opope_gemm`` kernel (``kernel_gemms``), and
+the operations and bytes of a token, a prompt, the weights and the KV
+cache. A configuration names its family module by ``"reference"``.
 """
 
 from __future__ import annotations
@@ -35,10 +42,136 @@ import jax.numpy as jnp
 import numpy as np
 
 from lib import weights as W
+from lib.costs import BF16, Gemm
+from lib.weights import Leaf
 
 HIGHEST = jax.lax.Precision.HIGHEST
 F32 = jnp.float32
 FP8_MAX = 448.0  # largest finite float8_e4m3fn
+
+
+# --------------------------------------------------------------------------
+# what the program serves, its parameter tree and its costs
+# --------------------------------------------------------------------------
+
+SERVED_KEYS = (
+    "n_layers", "d_model", "n_heads", "n_kv", "head_dim", "d_ff", "vocab",
+    "norm", "rope_frac", "rope_theta", "parallel_block", "tie_embeddings",
+    "qkv_bias", "param_dtype",
+)
+
+
+def served(arch) -> Dict:
+    """The values the program's ``ArchConfig`` would run, key by key, as the
+    configuration file's ``config`` names them."""
+    out = {k: getattr(arch, k) for k in SERVED_KEYS if k != "head_dim"}
+    out["head_dim"] = arch.head_dim_
+    return out
+
+
+def refuses(arch) -> Optional[str]:
+    """Why this reference cannot judge the program's ``ArchConfig``, or
+    None."""
+    if arch.moe is not None or arch.window or arch.attn_softcap or arch.final_softcap:
+        return "mechanisms the dense reference lacks"
+    return None
+
+
+def layout(cfg: Dict) -> List[Leaf]:
+    """The served parameter tree, leaf by leaf; a leaf's place in the list
+    is its key's index, so new leaves go last."""
+    d, hd, ff, v = cfg["d_model"], cfg["head_dim"], cfg["d_ff"], cfg["vocab"]
+    q, kv = cfg["n_heads"] * hd, cfg["n_kv"] * hd
+    w = cfg["param_dtype"]
+    ln = cfg["norm"] == "layernorm"
+    n = cfg["n_layers"]
+    out = [Leaf(("embed", "table"), (v, d), w, "embed", d, 0)]
+    if not cfg["tie_embeddings"]:
+        out.append(Leaf(("lm_head",), (v, d), w, "dense", d, 0))
+    out.append(Leaf(("final_norm", "scale"), (d,), "float32", "scale", d, 0))
+    if ln:
+        out.append(Leaf(("final_norm", "bias"), (d,), "float32", "bias", d, 0))
+    blk = ("blocks", 0)
+    for norm in ("norm_mixer", "norm_ffn"):
+        out.append(Leaf(blk + (norm, "scale"), (d,), "float32", "scale", d, n))
+        if ln:
+            out.append(Leaf(blk + (norm, "bias"), (d,), "float32", "bias", d, n))
+    out += [
+        Leaf(blk + ("attn", "wq", "w"), (d, q), w, "dense", d, n),
+        Leaf(blk + ("attn", "wk", "w"), (d, kv), w, "dense", d, n),
+        Leaf(blk + ("attn", "wv", "w"), (d, kv), w, "dense", d, n),
+        Leaf(blk + ("attn", "wo", "w"), (q, d), w, "dense", q, n),
+        Leaf(blk + ("mlp", "w_up"), (d, ff), w, "dense", d, n),
+        Leaf(blk + ("mlp", "w_gate"), (d, ff), w, "dense", d, n),
+        Leaf(blk + ("mlp", "w_down"), (ff, d), w, "dense", ff, n),
+    ]
+    if cfg["qkv_bias"]:
+        # last, so that the keys of every other leaf stay as they are
+        out += [Leaf(blk + ("attn", p, "b"), (m,), w, "bias", d, n)
+                for p, m in (("wq", q), ("wk", kv), ("wv", kv))]
+    return out
+
+
+def kernel_gemms(cfg: Dict) -> List[Gemm]:
+    """The GEMMs of one decoder layer, all run through ``opope_gemm``: the
+    Q, K and V projections, the attention output projection (with the
+    residual added in its epilogue), the MLP up projection, the gate
+    projection (with ``silu(.) * up`` in its epilogue) and the down
+    projection (with the residual). The LM head is counted in the step's
+    operations, not among the kernel GEMMs."""
+    d, hd, n = cfg["d_model"], cfg["head_dim"], cfg["n_layers"]
+    q, kv, ff = cfg["n_heads"] * hd, cfg["n_kv"] * hd, cfg["d_ff"]
+    return [
+        Gemm("q", d, q, 0, n),
+        Gemm("k", d, kv, 0, n),
+        Gemm("v", d, kv, 0, n),
+        Gemm("o", q, d, 1, n),
+        Gemm("up", d, ff, 0, n),
+        Gemm("gate", d, ff, 1, n),
+        Gemm("down", ff, d, 1, n),
+    ]
+
+
+def _matmul_params_per_layer(cfg: Dict) -> int:
+    return sum(g.k * g.n for g in kernel_gemms(cfg))
+
+
+def token_flops(cfg: Dict, context: int, lm_head: bool) -> float:
+    """Operations of one token through the model: every matmul, attention
+    over ``context`` positions (scores and the weighted sum), and the LM
+    head where the token produces logits."""
+    f = 2.0 * cfg["n_layers"] * _matmul_params_per_layer(cfg)
+    f += 4.0 * cfg["n_layers"] * cfg["n_heads"] * cfg["head_dim"] * context
+    if lm_head:
+        f += 2.0 * cfg["vocab"] * cfg["d_model"]
+    return f
+
+
+def prompt_flops(cfg: Dict, plen: int) -> float:
+    """A whole prompt prefilled: token ``p`` attends over ``p + 1``
+    positions; logits only for the last token."""
+    base = 2.0 * cfg["n_layers"] * _matmul_params_per_layer(cfg) * plen
+    attn = 4.0 * cfg["n_layers"] * cfg["n_heads"] * cfg["head_dim"] * (
+        plen * (plen + 1) / 2.0
+    )
+    return base + attn + 2.0 * cfg["vocab"] * cfg["d_model"]
+
+
+def weight_bytes(cfg: Dict) -> float:
+    """Bytes of the served bf16 weights (norm parameters are f32)."""
+    d, v, layers = cfg["d_model"], cfg["vocab"], cfg["n_layers"]
+    mats = layers * _matmul_params_per_layer(cfg) + (1 if cfg["tie_embeddings"] else 2) * v * d
+    norms = (2 * layers + 1) * d * (2 if cfg["norm"] == "layernorm" else 1)
+    return BF16 * mats + 4 * norms
+
+
+def kv_bytes_per_token(cfg: Dict) -> int:
+    return cfg["n_layers"] * 2 * cfg["n_kv"] * cfg["head_dim"] * BF16
+
+
+# --------------------------------------------------------------------------
+# the reference
+# --------------------------------------------------------------------------
 
 
 def _fq(x, axis, quant):
@@ -154,7 +287,8 @@ def logits_at(
     if quant not in (None, "fp8"):
         raise ValueError(f"unknown reference precision {quant!r}")
     cfgt = tuple(sorted((k, v) for k, v in cfg.items() if not isinstance(v, (dict, list))))
-    top = W.top_weights(cfg, seed)
+    leaves = layout(cfg)
+    top = W.top_weights(leaves, seed)
     table = top[("embed", "table")]
     xs = []
     for s in seqs:
@@ -164,7 +298,7 @@ def logits_at(
         xs.append(table[jnp.asarray(toks)].astype(F32))
     del table
     for layer in range(cfg["n_layers"]):
-        w = W.layer_weights(cfg, seed, layer)
+        w = W.layer_weights(leaves, seed, layer)
         xs = [_layer(cfgt, w, x, quant) for x in xs]
         del w
     out = []

@@ -88,6 +88,17 @@ def interpret():
     ops.set_default_backend(prev)
 
 
+def reader(name):
+    """The ``read`` function of ``bench/metrics/<name>.py``."""
+    import importlib.util
+
+    s = importlib.util.spec_from_file_location(
+        "bench_metric_" + name, os.path.join(BENCH, "metrics", name + ".py"))
+    mod = importlib.util.module_from_spec(s)
+    s.loader.exec_module(mod)
+    return mod.read
+
+
 def run_cell(root, name, seed=7, seconds=2.0, trace=False, backend="pallas_interpret", **kw):
     import time
 

@@ -69,13 +69,53 @@ def test_altered_tokens_are_not_correct(tiny_root, interpret, monkeypatch):
     assert c["value"] > c["limit"]
 
 
+FAMILY = '''"""A family of its own name: the dense family's shapes, costs and
+reference, re-exported, with a record of what the harness asked of it."""
+
+from reference import dense
+
+CALLS = []
+
+
+def _logged(name):
+    def call(*a, **k):
+        CALLS.append(name)
+        return getattr(dense, name)(*a, **k)
+    return call
+
+
+for _n in ("served", "refuses", "layout", "kernel_gemms", "token_flops", "prompt_flops",
+           "weight_bytes", "kv_bytes_per_token", "logits_at"):
+    globals()[_n] = _logged(_n)
+'''
+
+
+def _files(root):
+    out = {}
+    for d, _, names in os.walk(root):
+        for n in names:
+            if "__pycache__" not in d:
+                p = os.path.join(d, n)
+                out[os.path.relpath(p, root)] = open(p, "rb").read()
+    return out
+
+
 def test_a_cell_of_new_files_runs(tiny_root, interpret):
-    """A configuration, a mix, a cell and an end-to-end metric added as files
-    of their own and entries in BENCHMARK.json, with no edit to the harness."""
+    """A configuration of a family of its own, a mix, a cell and an
+    end-to-end metric added as files of their own and entries in
+    BENCHMARK.json, with no edit to any file the benchmark had."""
+    import time
+
+    from lib import harness, peaks, spec
+
     root, _ = tiny_root("stablelm-12b.pp4")
+    before = _files(os.path.join(root, "bench"))
     b = json.load(open(os.path.join(root, "BENCHMARK.json")))
     cfg = json.load(open(os.path.join(root, "bench/configs/tiny-stablelm-12b.pp4.json")))
     cfg["name"] = "growth"
+    cfg["reference"] = "growth_family"
+    with open(os.path.join(root, "bench/reference/growth_family.py"), "w") as f:
+        f.write(FAMILY)
     write_json(os.path.join(root, "bench/configs/growth.json"), cfg)
     write_json(os.path.join(root, "bench/traffic/shortmix.json"), {
         "prompt": {"dist": "lognormal", "median": 12, "sigma": 0.3, "min": 6, "max": 30},
@@ -92,11 +132,42 @@ def test_a_cell_of_new_files_runs(tiny_root, interpret):
     b["workloads"].append({"name": "growth.short", "config": "growth", "traffic": "shortmix", "chips": 1, "why": "t"})
     b["end_to_end"].append({"name": "requests_per_s", "unit": "requests/s", "better": "higher",
                             "bound": 0.05, "source": "host_clock", "workloads": ["growth.short"]})
+    b["per_layer"].append({"name": "step_mfu", "unit": "%", "better": "higher", "source": "device_trace",
+                           "layer": "whole step", "moves": "requests_per_s", "workloads": ["growth.short"]})
+    b["per_layer"] = [m for m in b["per_layer"] if m["name"] != "step_mfu" or "growth.short" in m["workloads"]]
     write_json(os.path.join(root, "BENCHMARK.json"), b)
+    after = _files(os.path.join(root, "bench"))
+    assert {k: after[k] for k in before} == before
     out = run_cell(root, "growth.short")
     assert out["correct"] is True
     assert out["metrics"]["requests_per_s"]["value"] > 0
     assert "itl_p95_ms" not in out["metrics"]
+    # the harness and the readers asked the new family, not the dense module
+    c = spec.load_cell(root, "growth.short")
+    traced = harness.run_once(c, 8, 2.5, True, t_start=time.perf_counter(), peaks=peaks.TPU_V5E,
+                              backend="pallas_interpret")
+    assert traced["correct"] is True
+    assert traced["metrics"]["step_mfu"]["value"] > 0
+    # (kernel_gemms is read only where a trace has opope_gemm time: on the
+    # chip, and in test_trace's reader tests)
+    assert {"served", "refuses", "layout", "token_flops", "prompt_flops",
+            "weight_bytes", "kv_bytes_per_token", "logits_at"} <= set(c.family.CALLS)
+
+
+@pytest.mark.parametrize("change", ["moe", "window", "attn_softcap"])
+def test_a_family_refuses_what_it_lacks(tiny_root, change):
+    """The dense family refuses a configuration with a mechanism its
+    reference lacks, whatever else the file states."""
+    from repro.configs.base import MoESpec
+
+    from lib import harness, spec
+
+    root, name = tiny_root("chatglm3-6b")
+    cell = spec.load_cell(root, name)
+    cell.config["changes"][change] = {"moe": MoESpec(n_experts=4, top_k=2, d_ff_expert=96),
+                                      "window": 16, "attn_softcap": 50.0}[change]
+    with pytest.raises(ValueError, match="^tiny-chatglm3-6b: mechanisms the dense reference lacks$"):
+        harness.served_config(cell)
 
 
 def test_longest_gap_names_what_the_host_did():

@@ -7,15 +7,12 @@ Times are in ns on the trace's clock; the window is [1000, 11000]. Device
 busy: [1500, 3500], [4000, 7000], [7500, 9500], [10500, 10800]. Idle:
 [1000, 1500], [3500, 4000], [7000, 7500], [9500, 10500], [10800, 11000]."""
 
-import importlib.util
-import os
 import types
 
 import pytest
 
+from conftest import reader
 from lib import spans, trace
-
-BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 REC = {
     "modules": [
@@ -50,14 +47,6 @@ REC = {
         [11000, 0, "bench.trace_end", "python", {}],
     ],
 }
-
-
-def reader(name):
-    s = importlib.util.spec_from_file_location(
-        "bench_metric_" + name, os.path.join(BENCH, "metrics", name + ".py"))
-    mod = importlib.util.module_from_spec(s)
-    s.loader.exec_module(mod)
-    return mod.read
 
 
 @pytest.fixture

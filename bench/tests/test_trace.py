@@ -5,6 +5,7 @@ Times are in ns on the trace's clock; the window is [1000, 11000]."""
 
 import pytest
 
+from conftest import reader
 from lib import trace
 
 REC = {
@@ -101,3 +102,25 @@ def test_recorded_tpu_excerpt():
     names = [k for k, _ in red.top_ops(50)]
     assert "decode/opope_gemm" in names and not any("while" in n or " = " in n for n in names)
     assert trace.op_name(ops[0][2]) == "fusion.61"
+
+
+def test_gemm_roofline_reads_the_family(red):
+    """The roofline reader takes its GEMMs from the run's family module."""
+    import types
+
+    from lib import costs, peaks
+
+    asked = []
+
+    class Family:
+        @staticmethod
+        def kernel_gemms(cfg):
+            asked.append(cfg)
+            return [costs.Gemm("g", 64, 64, 0, 2)]
+
+    run = types.SimpleNamespace(trace=red, family=Family, cfg={"tag": 1}, peaks=peaks.TPU_V5E)
+    # 500 prompt tokens: [500, 64] @ [64, 64] in 2 layers, bound by memory,
+    # against 2500 ns of opope_gemm time in the prefill
+    want = 2 * 2 * (500 * 64 + 64 * 64 + 500 * 64) / 819e9 / 2500e-9 * 100
+    assert reader("gemm_prefill_roofline")(run) == pytest.approx(want)
+    assert asked == [{"tag": 1}]
